@@ -32,7 +32,7 @@ from .precision import FormatSpec
 
 def _unpack_if_needed(x: jax.Array, spec: FormatSpec) -> jax.Array:
     if spec.packed:
-        return Q.unpack_int4(x, axis=x.ndim - 1)
+        return Q.unpack_int4_halves(x)
     return x
 
 

@@ -6,10 +6,13 @@ Conventions
 * Weights are quantized along the **contraction (K) axis** in groups of
   ``group`` (default 128, the paper's AWQ/GPTQ-compatible granularity):
   ``w_q[k, n] = round(w[k, n] / scale[k // group, n])``.
-* INT4 values live in int8 containers.  *Packed* tensors store two nibbles
-  per container along the quantized axis: packed[k] holds values
+* INT4 values live in int8 containers.  *Packed* weights store two
+  nibbles per container along the quantized axis: packed[k] holds values
   (2k) in the low nibble and (2k+1) in the high nibble — the same
-  sub-word ordering the offline packer (packing.py) preserves.
+  sub-word ordering the offline packer (packing.py) preserves.  Packed
+  int4 *KV* is half-split instead (:func:`pack_int4_halves`): packed[j]
+  holds head_dim index j low and j + head_dim/2 high, so the attention
+  kernels unpack with a lane concat rather than an interleave.
 * KV cache quantization is per-(token, head) absmax — each (t, h) row of
   head_dim values shares one scale.  This matches per-head dynamic KV
   quantization (KIVI/QServe-style) and keeps scale application lane-aligned
@@ -99,6 +102,26 @@ def unpack_int4(p: jax.Array, axis: int = 0) -> jax.Array:
     return stacked.reshape(shape)
 
 
+def pack_int4_halves(q: jax.Array) -> jax.Array:
+    """Pack int8-held int4 values two-per-byte along the last axis in
+    half-split order: low nibble = index j, high nibble = index j + n/2."""
+    n = q.shape[-1]
+    assert n % 2 == 0
+    lo, hi = q[..., :n // 2], q[..., n // 2:]
+    return ((lo & 0x0F) | (hi << 4)).astype(jnp.int8)
+
+
+def unpack_int4_halves(p: jax.Array) -> jax.Array:
+    """Inverse of pack_int4_halves, as int32 values.
+
+    Each nibble is sign-extended with int32 shifts and the halves are
+    concatenated along the last axis: the TPU's vector unit has no 8-bit
+    shifts, and this form lowers inside a Pallas kernel too (the
+    attention kernels call it on their KV tiles)."""
+    w = p.astype(jnp.int32)
+    return jnp.concatenate([(w << 28) >> 28, (w << 24) >> 28], axis=-1)
+
+
 # -- activations (dynamic per-token) ----------------------------------------
 
 
@@ -126,8 +149,8 @@ def quantize_kv(kv: jax.Array, spec: FormatSpec):
         return (kv.astype(jnp.float32) / scale).astype(spec.dtype), scale
     scale = absmax_scale(kv, axis=-1, qmax=spec.qmax)
     q = quantize_int(kv, scale, spec.bits)
-    if spec.packed:  # int4: pack head_dim two-per-byte
-        q = pack_int4(q, axis=q.ndim - 1)
+    if spec.packed:  # int4: pack head_dim two-per-byte, half-split
+        q = pack_int4_halves(q)
     return q, scale
 
 
@@ -138,7 +161,7 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, spec: FormatSpec,
             return q.astype(dtype)
         return (q.astype(jnp.float32) * scale).astype(dtype)
     if spec.packed:
-        q = unpack_int4(q, axis=q.ndim - 1)
+        q = unpack_int4_halves(q)
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 

@@ -4,22 +4,25 @@ The paper's attention pipeline (§3.4) with adaptive head alignment (§4.2)
 and the KV memory loading pipeline (§4.4), TPU-native:
 
 * **Adaptive head alignment**: Q is the tensor that adapts — the wrapper
-  reshapes it once per decode step into (B, Hkv, rep, D) so each grid step
-  holds the `rep` grouped-query heads that share one quantized K/V head,
-  and the dot contracts against the low-bit K tile's cast directly.  K/V
-  are never materialized in bf16 in HBM.
-* **KV memory loading pipeline**: grid dimension 2 walks (block_s × D) KV
-  tiles; ``pallas_call`` pipelines the next tile's HBM→VMEM DMA under the
-  current tile's dequant (VPU) + QKᵀ/PV (MXU) — the triple overlap of
-  Fig. 10.  Online-softmax state (m, l, acc) lives in VMEM scratch across
-  grid steps, flash-decoding style.
-* Dequantization is nibble-unpack + I2F + per-(token, head) scale — scale
-  is applied to the score/prob matrices (algebraic hoisting), so the MXU
-  operands are plain casts of the stored integers.
+  reshapes it once per step into (B, Hkv, R, D) so the ``rep``
+  grouped-query heads that share one quantized K/V head sit in one q
+  tile.  K/V are never materialized in bf16 in HBM.
+* **KV memory loading pipeline**: the grid is (batch, KV block), and each
+  step loads one block of *every* KV head — a (bs, Hkv, Dstore) tile and
+  its (bs, Hkv, 1) scales, whose two minor dimensions are the array's
+  own, as the TPU's (8, 128) tiling rule requires for any Hkv.  The
+  kernel loops over the heads statically.  ``pallas_call`` pipelines the
+  next block's HBM→VMEM DMA under the current block's dequant (VPU) +
+  QKᵀ/PV (MXU) — the triple overlap of Fig. 10.  Online-softmax state
+  (m, l, acc), one slice per head, lives in VMEM scratch across grid
+  steps, flash-decoding style.
+* Dequantization is int32 nibble-unpack (kv4) + I2F + per-(token, head)
+  scale, rounded to bf16 for the MXU.
 
-VMEM per step at block_s=256, D=128, rep≤16: k/v tiles 2·256·128 B int8 +
-q 16·128·2 B + scratch (16·128·4 + 2·16·4) ≈ 90 KiB — double-buffered
-comfortably within VMEM.
+VMEM per step at bs=64, Hkv=5, D=64, R=96 q rows, with the two minor
+dims padded to the dtype's native tile ((32, 128) int8, (8, 128) f32):
+int8 K/V + f32 scale tiles ≈ 1 MiB, q/out ≈ 0.25 MiB, scratch ≈ 0.7 MiB
+— ≈ 2 MiB, double-buffered well within VMEM.
 
 The per-block online-softmax update (:func:`flash_block_update`) is shared
 with the *paged* decode kernel (kernels/paged_kvattn.py), which walks pool
@@ -44,27 +47,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import quantize as Q
+
 NEG_INF = -1e30
 #: "no sliding window" sentinel — any int32 ``pos - NO_WINDOW`` stays
 #: negative for every reachable position, so the window mask is a no-op.
 NO_WINDOW = 1 << 30
 
 
-def _dequant_tile(q_ints: jax.Array, scale: jax.Array, packed: bool,
-                  d: int) -> jax.Array:
-    """(bs, Dstore) ints + (bs,) scales → (bs, d) bf16."""
+def _dequant_tile(q_ints: jax.Array, scale: jax.Array,
+                  packed: bool) -> jax.Array:
+    """(bs, Dstore) ints + (bs, 1) scales → (bs, d) bf16.
+
+    int4 KV is packed half-split (low nibbles hold the first half of
+    head_dim), so unpacking is a lane concat."""
     if packed:
-        lo = ((q_ints << 4).astype(jnp.int8) >> 4)
-        hi = (q_ints >> 4).astype(jnp.int8)
-        q_ints = jnp.stack([lo, hi], axis=2).reshape(q_ints.shape[0], d)
-    return (q_ints.astype(jnp.float32) * scale[:, None]).astype(jnp.bfloat16)
+        q_ints = Q.unpack_int4_halves(q_ints)
+    return (q_ints.astype(jnp.float32) * scale).astype(jnp.bfloat16)
 
 
 def flash_block_update(q, kt, ks, vt, vs, pos, window, base,
                        m_ref, l_ref, acc_ref, *, d, packed, kv_is_float):
-    """One flash-decoding step over a (bs, Dstore) KV tile.
+    """One flash-decoding step of one KV head over a (bs, Dstore) tile.
 
-    ``base`` is the *logical* position of the tile's first token — the
+    ``ks``/``vs`` are the tile's (bs, 1) per-token scales.  ``base`` is
+    the *logical* position of the tile's first token — the
     only place the dense and paged kernels differ (dense: ``s_blk *
     block_s`` over the slab; paged: ``logical_block * block_size``, while
     the tile itself was DMA'd from wherever the block table pointed).
@@ -77,9 +84,9 @@ def flash_block_update(q, kt, ks, vt, vs, pos, window, base,
     lets a shorter grid (live context) match a longer one bitwise.
     """
     if kv_is_float:
-        kd = (kt.astype(jnp.float32) * ks[:, None]).astype(jnp.bfloat16)
+        kd = (kt.astype(jnp.float32) * ks).astype(jnp.bfloat16)
     else:
-        kd = _dequant_tile(kt, ks, packed, d)           # (bs, D) bf16
+        kd = _dequant_tile(kt, ks, packed)              # (bs, D) bf16
 
     s = jax.lax.dot_general(q, kd, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -98,43 +105,70 @@ def flash_block_update(q, kt, ks, vt, vs, pos, window, base,
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
 
     if kv_is_float:
-        vd = (vt.astype(jnp.float32) * vs[:, None]).astype(jnp.bfloat16)
+        vd = (vt.astype(jnp.float32) * vs).astype(jnp.bfloat16)
     else:
-        vd = _dequant_tile(vt, vs, packed, d)           # (bs, D)
+        vd = _dequant_tile(vt, vs, packed)              # (bs, D)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p.astype(jnp.bfloat16), vd, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
+def flash_init(m_ref, l_ref, acc_ref):
+    """Reset the online-softmax scratch of every KV head."""
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def flash_heads_update(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos, window,
+                       base, m_ref, l_ref, acc_ref, *, rep, d, packed,
+                       kv_is_float):
+    """:func:`flash_block_update` for every KV head of one block.
+
+    One grid step holds all ``Hkv`` heads of a block: the K/V tiles are
+    ``(1, bs, Hkv, Dstore)`` and the scales ``(1, bs, Hkv, 1)``, whose two
+    minor dimensions are the full array's — the TPU tiling rule that a
+    one-head ``(bs, 1, Dstore)`` tile breaks whenever ``Hkv`` is not 1.
+    q rows are (token, group) pairs in token-major order (r = t*rep + g):
+    row r's causal frontier is ``pos + r // rep``.  T == 1 keeps every
+    row at ``pos`` — bitwise the single-token decode."""
+    R = m_ref.shape[1]
+    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
+    for h in range(m_ref.shape[0]):
+        flash_block_update(
+            q_ref[0, h], k_ref[0, :, h], ks_ref[0, :, h], v_ref[0, :, h],
+            vs_ref[0, :, h], qpos, window, base,
+            m_ref.at[h], l_ref.at[h], acc_ref.at[h],
+            d=d, packed=packed, kv_is_float=kv_is_float)
+
+
 def flash_store(o_ref, m_ref, l_ref, acc_ref):
-    """Final normalized store of the online-softmax accumulator."""
+    """Final normalized store of every head's online-softmax accumulator."""
     l = jnp.maximum(l_ref[...], 1e-20)
-    o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _kvattn_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, win_ref,
+def flash_scratch(Hkv: int, R: int, D: int):
+    """VMEM scratch (m, l, acc) for one grid cell's ``Hkv`` heads."""
+    return [pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, 1), jnp.float32),
+            pltpu.VMEM((Hkv, R, D), jnp.float32)]
+
+
+def _kvattn_kernel(pos_ref, win_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
                    o_ref, m_ref, l_ref, acc_ref, *, block_s, n_s, d, rep,
                    packed, kv_is_float):
-    s_blk = pl.program_id(2)
+    b = pl.program_id(0)
+    s_blk = pl.program_id(1)
 
     @pl.when(s_blk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        flash_init(m_ref, l_ref, acc_ref)
 
-    # q rows are (token, group) pairs in token-major order (r = t*rep + g):
-    # row r's query is the chunk's t-th token, so its causal frontier is
-    # pos + r // rep.  T == 1 degenerates to qpos == pos for every row —
-    # bitwise the original single-token decode.
-    R = m_ref.shape[0]
-    pos = pos_ref[0, 0]        # this slot's first (oldest) query position
-    win = win_ref[0, 0]
-    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
-    flash_block_update(
-        q_ref[0, 0], k_ref[0, :, 0], ks_ref[0, :, 0], v_ref[0, :, 0],
-        vs_ref[0, :, 0], qpos, win, s_blk * block_s, m_ref, l_ref, acc_ref,
-        d=d, packed=packed, kv_is_float=kv_is_float)
+    flash_heads_update(
+        q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref[b], win_ref[0],
+        s_blk * block_s, m_ref, l_ref, acc_ref, rep=rep, d=d,
+        packed=packed, kv_is_float=kv_is_float)
 
     @pl.when(s_blk == n_s - 1)
     def _store():
@@ -147,11 +181,11 @@ def _kvattn_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, win_ref,
 def kvattn_decode_grouped(
     q: jax.Array,          # (B, Hkv, R, D) bf16 — adaptive head alignment
     k: jax.Array,          # (B, S, Hkv, Dstore) int8 / fp8 / bf16
-    k_scale: jax.Array,    # (B, S, Hkv) f32
+    k_scale: jax.Array,    # (B, S, Hkv, 1) f32
     v: jax.Array,
     v_scale: jax.Array,
-    pos: jax.Array,        # (B, 1) int32: per-slot *first* query position
-    window: jax.Array,     # (1, 1) int32: sliding window (NO_WINDOW = off)
+    pos: jax.Array,        # (B,) int32: per-slot *first* query position
+    window: jax.Array,     # (1,) int32: sliding window (NO_WINDOW = off)
     *,
     packed: bool,
     kv_is_float: bool = False,
@@ -161,10 +195,11 @@ def kvattn_decode_grouped(
 ) -> jax.Array:
     """Multi-query grouped decode attention.
 
-    The q tile carries ``R = T * rep`` rows per (batch, kv-head) grid cell
-    in token-major order — ``rep`` consecutive rows share one causal
+    The q tile carries ``R = T * rep`` rows per (batch, kv-head) in
+    token-major order — ``rep`` consecutive rows share one causal
     frontier, and frontiers step by one every ``rep`` rows.  ``rep=None``
-    (back-compat) treats the whole tile as a single token (T == 1).
+    (back-compat) treats the whole tile as a single token (T == 1).  The
+    grid is (batch, block); each step covers every KV head.
     """
     B, Hkv, R, D = q.shape
     if rep is None:
@@ -176,33 +211,30 @@ def kvattn_decode_grouped(
     assert S % bs == 0, (S, bs)
     n_s = S // bs
 
-    grid = (B, Hkv, n_s)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,         # positions, window
+        grid=(B, n_s),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, R, D), lambda b, s, pos, win: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, Ds), lambda b, s, pos, win: (b, s, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, 1), lambda b, s, pos, win: (b, s, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, Ds), lambda b, s, pos, win: (b, s, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, 1), lambda b, s, pos, win: (b, s, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, R, D),
+                               lambda b, s, pos, win: (b, 0, 0, 0)),
+        scratch_shapes=flash_scratch(Hkv, R, D),
+    )
     kernel = functools.partial(
         _kvattn_kernel, block_s=bs, n_s=n_s, d=D, rep=rep, packed=packed,
         kv_is_float=kv_is_float)
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, Ds), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, h, s: (b, s, h)),
-            pl.BlockSpec((1, bs, 1, Ds), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bs, 1), lambda b, h, s: (b, s, h)),
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, h, s: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, R, D), lambda b, h, s: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, D), jnp.float32),
-        ],
         interpret=interpret,
-    )(q, k, k_scale, v, v_scale, pos, window)
+    )(pos.astype(jnp.int32), window.astype(jnp.int32),
+      q, k, k_scale, v, v_scale)
 
 
 # Paged decode lives in kernels/paged_kvattn.py: the block-table

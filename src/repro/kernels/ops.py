@@ -140,8 +140,8 @@ def kvattn_decode(q: jax.Array, cache: KVCache, spec: FormatSpec,
     qg, rk = _group_rows(q, Hkv, rep)       # adaptive head alignment (§4.2)
     out = _kvattn.kvattn_decode_grouped(
         qg.astype(jnp.bfloat16),
-        cache.k, cache.k_scale[..., 0], cache.v, cache.v_scale[..., 0],
-        _norm_pos(pos, B).reshape(B, 1), _norm_window(window).reshape(1, 1),
+        cache.k, cache.k_scale, cache.v, cache.v_scale,
+        _norm_pos(pos, B), _norm_window(window),
         packed=spec.packed, kv_is_float=spec.is_float,
         block_s=block_s, rep=rk, interpret=INTERPRET)
     return _ungroup_rows(out, B, T, Hkv, rep, rk, D).astype(q.dtype)
@@ -174,7 +174,7 @@ def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
         n_live = blocks_needed(max_live + T - 1, cache.block_size)
     out = _pkvattn.paged_kvattn_decode_grouped(
         qg.astype(jnp.bfloat16),
-        cache.k, cache.k_scale[..., 0], cache.v, cache.v_scale[..., 0],
+        cache.k, cache.k_scale, cache.v, cache.v_scale,
         cache.block_table, _norm_pos(pos, B), _norm_window(window),
         packed=spec.packed, kv_is_float=spec.is_float,
         n_live_blocks=n_live, rep=rk, interpret=INTERPRET)

@@ -25,14 +25,14 @@ clamped to a real pool block by the wrapper) is handled by the logical
 online-softmax state (see kvattn.flash_block_update), so garbage blocks
 contribute nothing, bitwise.
 
-Per-block compute is :func:`kvattn.flash_block_update`, shared with the
+Per-block compute is :func:`kvattn.flash_heads_update`, shared with the
 dense decode kernel — the two kernels are bit-identical over equal logical
 contents at equal block granularity, which is what keeps the serving
 engine's dense and paged backends byte-identical under greedy decoding.
-
-VMEM per step at block_size=64, D=128, rep≤16: k/v tiles 2·64·128 B int8
-+ q 16·128·2 B + scratch (16·128·4 + 2·16·4) ≈ 29 KiB — small blocks
-double-buffer trivially; the table and positions live in SMEM.
+The grid is (slot, logical block); one step DMAs the pool block's tiles
+for every KV head at once (see kernels/kvattn.py for the tiling rule
+this satisfies and its VMEM budget); the table and positions live in
+SMEM.
 """
 from __future__ import annotations
 
@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .kvattn import NEG_INF, flash_block_update, flash_store
+from .kvattn import (flash_heads_update, flash_init, flash_scratch,
+                     flash_store)
 
 
 def _paged_kvattn_kernel(tbl_ref, pos_ref, win_ref,
@@ -51,27 +52,18 @@ def _paged_kvattn_kernel(tbl_ref, pos_ref, win_ref,
                          o_ref, m_ref, l_ref, acc_ref, *,
                          block_size, n_s, d, rep, packed, kv_is_float):
     b = pl.program_id(0)
-    s_blk = pl.program_id(2)   # logical block index within the slot
+    s_blk = pl.program_id(1)   # logical block index within the slot
 
     @pl.when(s_blk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        flash_init(m_ref, l_ref, acc_ref)
 
-    # q rows are (token, group) pairs in token-major order (r = t*rep + g):
-    # row r's causal frontier is pos + r // rep.  T == 1 keeps qpos == pos
-    # for every row — bitwise the original single-token decode.
-    R = m_ref.shape[0]
-    pos = pos_ref[b]            # this slot's first (oldest) query position
-    win = win_ref[0]
-    qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
     # the K/V tiles were DMA'd from pool block tbl[b, s_blk]; their
     # *logical* positions start at s_blk * block_size
-    flash_block_update(
-        q_ref[0, 0], k_ref[0, :, 0], ks_ref[0, :, 0], v_ref[0, :, 0],
-        vs_ref[0, :, 0], qpos, win, s_blk * block_size,
-        m_ref, l_ref, acc_ref, d=d, packed=packed, kv_is_float=kv_is_float)
+    flash_heads_update(
+        q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref[b], win_ref[0],
+        s_blk * block_size, m_ref, l_ref, acc_ref, rep=rep, d=d,
+        packed=packed, kv_is_float=kv_is_float)
 
     @pl.when(s_blk == n_s - 1)
     def _store():
@@ -85,7 +77,7 @@ def _paged_kvattn_kernel(tbl_ref, pos_ref, win_ref,
 def paged_kvattn_decode_grouped(
     q: jax.Array,            # (B, Hkv, R, D) bf16 — adaptive head alignment
     k: jax.Array,            # (n_blocks, block_size, Hkv, Dstore) pool
-    k_scale: jax.Array,      # (n_blocks, block_size, Hkv) f32
+    k_scale: jax.Array,      # (n_blocks, block_size, Hkv, 1) f32
     v: jax.Array,
     v_scale: jax.Array,
     block_table: jax.Array,  # (B, blocks_per_slot) int32; n_blocks=unmapped
@@ -99,10 +91,11 @@ def paged_kvattn_decode_grouped(
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-query paged decode: the q tile carries ``R = T * rep`` rows
-    per (slot, kv-head) grid cell in token-major order — ``rep``
-    consecutive rows share one causal frontier, frontiers step by one
-    every ``rep`` rows.  ``rep=None`` (back-compat) treats the whole tile
-    as one token.  This is the single kernel behind chunked prefill,
+    per (slot, kv-head) in token-major order — ``rep`` consecutive rows
+    share one causal frontier, frontiers step by one every ``rep`` rows.
+    ``rep=None`` (back-compat) treats the whole tile as one token.  The
+    grid is (slot, logical block); each step covers every KV head of the
+    pool block.  This is the single kernel behind chunked prefill,
     preemption replay, and decode."""
     B, Hkv, R, D = q.shape
     if rep is None:
@@ -120,26 +113,22 @@ def paged_kvattn_decode_grouped(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,         # block table, positions, window
-        grid=(B, Hkv, n_s),
+        grid=(B, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1, R, D),
-                         lambda b, h, s, tbl, pos, win: (b, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, Ds),
-                         lambda b, h, s, tbl, pos, win: (tbl[b, s], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda b, h, s, tbl, pos, win: (tbl[b, s], 0, h)),
-            pl.BlockSpec((1, bs, 1, Ds),
-                         lambda b, h, s, tbl, pos, win: (tbl[b, s], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1),
-                         lambda b, h, s, tbl, pos, win: (tbl[b, s], 0, h)),
+            pl.BlockSpec((1, Hkv, R, D),
+                         lambda b, s, tbl, pos, win: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, Ds),
+                         lambda b, s, tbl, pos, win: (tbl[b, s], 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, 1),
+                         lambda b, s, tbl, pos, win: (tbl[b, s], 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, Ds),
+                         lambda b, s, tbl, pos, win: (tbl[b, s], 0, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv, 1),
+                         lambda b, s, tbl, pos, win: (tbl[b, s], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, D),
-                               lambda b, h, s, tbl, pos, win: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, R, D),
+                               lambda b, s, tbl, pos, win: (b, 0, 0, 0)),
+        scratch_shapes=flash_scratch(Hkv, R, D),
     )
     kernel = functools.partial(
         _paged_kvattn_kernel, block_size=bs, n_s=n_s, d=D, rep=rep,

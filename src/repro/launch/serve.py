@@ -1,13 +1,16 @@
 """Serving driver: the continuous-batching engine with Poisson arrivals.
 
-Runs the real engine on this host (reduced configs are CPU-feasible);
-reports throughput / TTFT / latency percentiles, the paper's §5 metrics.
-Engine knobs come from :meth:`EngineConfig.add_cli_args` — the same flags
-the serving benchmarks use.
+Runs the real engine on the default JAX backend and reports throughput
+/ TTFT / latency percentiles, the paper's §5 metrics.  Engine knobs come
+from :meth:`EngineConfig.add_cli_args` — the same flags the serving
+benchmarks use.  ``--reduced`` (the default) serves the 2-layer variant,
+which the CPU runs in seconds; ``--full`` serves the published widths.
 
 Usage:
     python -m repro.launch.serve --arch smollm-360m --reduced \
         --requests 32 --rate 4.0 --policy w4a16kv8
+    python -m repro.launch.serve --full --cache-kind paged --slots 8 \
+        --max-seq 1024 --requests 8 --prompt-len 256 --max-new 32
 """
 import argparse
 import sys
@@ -33,6 +36,13 @@ def main(argv=None) -> int:
     except EngineError as e:
         print(f"invalid engine configuration: {e}", file=sys.stderr)
         return 2
+    import jax
+
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
+    devs = jax.devices()
+    print(f"platform={devs[0].platform} device_kind={devs[0].device_kind} "
+          f"devices={len(devs)}", flush=True)
     eng = Engine(config)
     vocab = config.model.vocab
     rng = np.random.default_rng(config.seed)

@@ -30,15 +30,37 @@ from typing import Dict, Optional
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    """TPU v5e chip constants (DESIGN.md §2)."""
-    name: str = "tpu-v5e"
-    peak_flops: float = 197e12        # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9             # bytes/s per chip
-    ici_bw: float = 50e9              # bytes/s per link
-    hbm_bytes: float = 16e9           # capacity per chip
+    """Published per-chip peaks of one accelerator."""
+    name: str
+    peak_flops: float                 # bf16 FLOP/s per chip
+    peak_int8_ops: float              # int8 OP/s per chip
+    hbm_bw: float                     # bytes/s per chip
+    ici_bw: float                     # bytes/s per link
+    hbm_bytes: float                  # capacity per chip
 
 
-HW = Hardware()
+#: Peaks keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM
+#: at 819 GB/s, 1,600 Gbit/s interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, Hardware] = {
+    "TPU v5 lite": Hardware(name="tpu-v5e", peak_flops=197e12,
+                            peak_int8_ops=393e12, hbm_bw=819e9,
+                            ici_bw=50e9, hbm_bytes=16e9),
+}
+
+
+def peaks_for(device_kind: str) -> Hardware:
+    """The peaks of ``device_kind``; a kind not in :data:`PEAKS` is an
+    error, never a silent v5e default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
+
+#: the dry run's target chip, named explicitly
+HW = peaks_for("TPU v5 lite")
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2, "u16": 2,

@@ -186,8 +186,29 @@ def test_prop_kv4_pack_unpack_inverse_on_quantized():
     kv = jax.random.normal(jax.random.PRNGKey(11), (2, 8, 2, 32),
                            jnp.float32).astype(jnp.bfloat16)
     q_packed, scale = Q.quantize_kv(kv, spec)
-    q_vals = Q.unpack_int4(q_packed, axis=q_packed.ndim - 1)
+    q_vals = Q.unpack_int4_halves(q_packed)
     assert int(jnp.max(q_vals)) <= 7 and int(jnp.min(q_vals)) >= -7
-    repacked = Q.pack_int4(q_vals, axis=q_vals.ndim - 1)
+    repacked = Q.pack_int4_halves(q_vals)
     np.testing.assert_array_equal(np.asarray(repacked),
                                   np.asarray(q_packed))
+
+
+@pytest.mark.parametrize("D", [2, 8, 64, 128])
+def test_kv4_half_split_order_roundtrip(D):
+    """Packed int4 KV is half-split: byte j holds head_dim index j in its
+    low nibble and j + D/2 in its high nibble (the attention kernels
+    unpack with a lane concat); every value in [-8, 7] round-trips."""
+    q = jax.random.randint(jax.random.PRNGKey(D), (3, 2, D), -8, 8,
+                           jnp.int32).astype(jnp.int8)
+    p = np.asarray(Q.pack_int4_halves(q)).astype(np.int32)
+    assert p.shape == (3, 2, D // 2)
+    lo = ((p & 0xF) ^ 8) - 8                 # sign-extend the nibbles
+    hi = (((p >> 4) & 0xF) ^ 8) - 8
+    qn = np.asarray(q, np.int32)
+    np.testing.assert_array_equal(lo, qn[..., :D // 2])
+    np.testing.assert_array_equal(hi, qn[..., D // 2:])
+    np.testing.assert_array_equal(
+        np.asarray(Q.unpack_int4_halves(Q.pack_int4_halves(q))), qn)
+    # weights keep the interleaved order
+    w = np.asarray(Q.pack_int4(q, axis=2)).astype(np.int32)
+    np.testing.assert_array_equal(((w & 0xF) ^ 8) - 8, qn[..., 0::2])

@@ -6,8 +6,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.roofline import hlo_cost
-from repro.roofline.analysis import (HW, RooflineTerms,
-                                     collective_bytes_from_hlo, model_flops)
+from repro.roofline.analysis import (HW, PEAKS, RooflineTerms,
+                                     collective_bytes_from_hlo, model_flops,
+                                     peaks_for)
 
 
 class TestHloCost:
@@ -87,6 +88,17 @@ class TestRooflineTerms:
         assert t.collective_s == pytest.approx(1e9 / HW.ici_bw)
         assert t.dominant == "compute"
         assert t.useful_flops_ratio == pytest.approx(0.5)
+
+    def test_peaks_keyed_by_device_kind(self):
+        v5e = peaks_for("TPU v5 lite")
+        assert v5e is HW and v5e is PEAKS["TPU v5 lite"]
+        assert (v5e.peak_flops, v5e.peak_int8_ops, v5e.hbm_bw,
+                v5e.hbm_bytes) == (197e12, 393e12, 819e9, 16e9)
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite"])
+    def test_unknown_device_kind_raises(self, kind):
+        with pytest.raises(KeyError, match="no published peaks"):
+            peaks_for(kind)
 
     def test_model_flops_modes(self):
         cfg = get_config("smollm-360m")

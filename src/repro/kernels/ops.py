@@ -147,6 +147,14 @@ def kvattn_decode(q: jax.Array, cache: KVCache, spec: FormatSpec,
     return _ungroup_rows(out, B, T, Hkv, rep, rk, D).astype(q.dtype)
 
 
+def grid_blocks(max_live: int, t: int, block_size: int) -> int:
+    """Extent of the paged kernel's block axis for a ``t``-row step whose
+    first query rows reach ``max_live`` tokens: widened by ``t - 1`` so
+    the chunk's last token's frontier stays in the grid (the kernel
+    clips it to the table's width)."""
+    return blocks_needed(max_live + t - 1, block_size)
+
+
 def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
                         pos, window=None,
                         max_live: Optional[int] = None) -> jax.Array:
@@ -171,7 +179,7 @@ def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
     qg, rk = _group_rows(q, Hkv, rep)       # adaptive head alignment (§4.2)
     n_live = None
     if max_live is not None:
-        n_live = blocks_needed(max_live + T - 1, cache.block_size)
+        n_live = grid_blocks(max_live, T, cache.block_size)
     out = _pkvattn.paged_kvattn_decode_grouped(
         qg.astype(jnp.bfloat16),
         cache.k, cache.k_scale, cache.v, cache.v_scale,

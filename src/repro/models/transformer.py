@@ -284,65 +284,82 @@ def decode_step(params, cfg: ModelConfig, policy: PrecisionPolicy,
     tile height and ``max_live`` (static) the batch's live-context
     high-water mark bounding paged traffic — the serving engine sets all
     three.
+
+    Every device op of the step lies under one ``jax.named_scope`` stage
+    (``embed``, ``layers`` around the scan, and in its body ``qkv``,
+    ``kv_append``, ``attn``, ``attn_out`` and ``ffn``; then ``lm_head``),
+    which the profiler's trace carries in each op's name stack.
     """
     paged = isinstance(cache, PKV.PagedKVCache)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(policy.compute_dtype)
-    B, T, d = x.shape
-    pos = jnp.asarray(pos, jnp.int32)
-    per_slot = pos.ndim == 1
-    # stacked cache leaves carry (L, ...): dense k is (L, B, S, H, Ds),
-    # paged tables are (L, n_slots, blocks_per_slot) mapping bs-token blocks
-    if paged:
-        n_ctx = cache.block_table.shape[2] * cache.k.shape[2]
-    else:
-        n_ctx = cache.k.shape[2]
-    if not cfg.use_rope:
-        sp = C.sinusoidal_pos(n_ctx, d)
-        if per_slot:
-            idx = pos[:, None] + jnp.arange(T)[None]
-            x = x + jnp.take(sp, idx, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens,
+                     axis=0).astype(policy.compute_dtype)
+        B, T, d = x.shape
+        pos = jnp.asarray(pos, jnp.int32)
+        per_slot = pos.ndim == 1
+        # stacked cache leaves carry (L, ...): dense k is (L, B, S, H, Ds),
+        # paged tables are (L, n_slots, blocks_per_slot) mapping bs-token
+        # blocks
+        if paged:
+            n_ctx = cache.block_table.shape[2] * cache.k.shape[2]
         else:
-            x = x + jax.lax.dynamic_slice_in_dim(sp, pos, T)[None]
-    if per_slot:
-        rope_pos = pos[:, None] + jnp.arange(T)[None]
-    else:
-        rope_pos = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
+            n_ctx = cache.k.shape[2]
+        if not cfg.use_rope:
+            sp = C.sinusoidal_pos(n_ctx, d)
+            if per_slot:
+                idx = pos[:, None] + jnp.arange(T)[None]
+                x = x + jnp.take(sp, idx, axis=0)
+            else:
+                x = x + jax.lax.dynamic_slice_in_dim(sp, pos, T)[None]
+        if per_slot:
+            rope_pos = pos[:, None] + jnp.arange(T)[None]
+        else:
+            rope_pos = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
 
     def body(xc, sl):
         lp, cache_l, idx = sl
-        h = C.rms_norm(xc, lp["ln1"], cfg.norm_eps)
-        q, k, v = qkv(h, lp, cfg, policy, impl)
-        if cfg.use_rope:
-            q = C.apply_rope(q, rope_pos, rotary_pct=cfg.rotary_pct,
-                             theta=cfg.rope_theta)
-            k = C.apply_rope(k, rope_pos, rotary_pct=cfg.rotary_pct,
-                             theta=cfg.rope_theta)
-        if paged:
-            cache_l = PKV.append_paged(cache_l, k, v, pos, policy.kv,
-                                       valid=valid)
-        elif per_slot:
-            cache_l = KV.append_per_slot(cache_l, k, v, pos, policy.kv,
-                                         valid=valid)
-        else:
-            cache_l = KV.append(cache_l, k, v, pos, policy.kv)
-        win = layer_window(cfg, idx)
-        attn = C.attend_decode(q, cache_l, policy.kv, pos, window=win,
-                               impl=attn_impl
-                               or ("fused" if impl != "pallas" else impl),
-                               block_s=attn_block_s, max_live=max_live)
-        xc = xc + C.linear(attn.reshape(B, T, -1), lp["wo"], policy, impl)
-        h2 = C.rms_norm(xc, lp["ln2"], cfg.norm_eps)
-        xc = xc + ffn(h2, lp, cfg, policy, impl)
+        with jax.named_scope("qkv"):
+            h = C.rms_norm(xc, lp["ln1"], cfg.norm_eps)
+            q, k, v = qkv(h, lp, cfg, policy, impl)
+            if cfg.use_rope:
+                q = C.apply_rope(q, rope_pos, rotary_pct=cfg.rotary_pct,
+                                 theta=cfg.rope_theta)
+                k = C.apply_rope(k, rope_pos, rotary_pct=cfg.rotary_pct,
+                                 theta=cfg.rope_theta)
+        with jax.named_scope("kv_append"):
+            if paged:
+                cache_l = PKV.append_paged(cache_l, k, v, pos, policy.kv,
+                                           valid=valid)
+            elif per_slot:
+                cache_l = KV.append_per_slot(cache_l, k, v, pos, policy.kv,
+                                             valid=valid)
+            else:
+                cache_l = KV.append(cache_l, k, v, pos, policy.kv)
+        with jax.named_scope("attn"):
+            win = layer_window(cfg, idx)
+            attn = C.attend_decode(q, cache_l, policy.kv, pos, window=win,
+                                   impl=attn_impl
+                                   or ("fused" if impl != "pallas" else impl),
+                                   block_s=attn_block_s, max_live=max_live)
+        with jax.named_scope("attn_out"):
+            xc = xc + C.linear(attn.reshape(B, T, -1), lp["wo"], policy,
+                               impl)
+        with jax.named_scope("ffn"):
+            h2 = C.rms_norm(xc, lp["ln2"], cfg.norm_eps)
+            xc = xc + ffn(h2, lp, cfg, policy, impl)
         return xc, cache_l
 
-    x, new_cache = jax.lax.scan(
-        body, x, (params["layers"], cache, jnp.arange(cfg.n_layers)))
-    if valid is None:
-        h_sel = x[:, -1]
-    else:
-        # each slot samples from its last *valid* chunk row (idle slots
-        # clamp to row 0 — their logits are discarded by the engine)
-        idx = jnp.clip(valid.astype(jnp.int32) - 1, 0, T - 1)
-        h_sel = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    h_last = C.rms_norm(h_sel, params["final_norm"], cfg.norm_eps)
-    return lm_logits(params, h_last), new_cache
+    with jax.named_scope("layers"):
+        x, new_cache = jax.lax.scan(
+            body, x, (params["layers"], cache, jnp.arange(cfg.n_layers)))
+    with jax.named_scope("lm_head"):
+        if valid is None:
+            h_sel = x[:, -1]
+        else:
+            # each slot samples from its last *valid* chunk row (idle
+            # slots clamp to row 0 — their logits are discarded by the
+            # engine)
+            idx = jnp.clip(valid.astype(jnp.int32) - 1, 0, T - 1)
+            h_sel = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
+        h_last = C.rms_norm(h_sel, params["final_norm"], cfg.norm_eps)
+        return lm_logits(params, h_last), new_cache

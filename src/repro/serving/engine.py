@@ -78,6 +78,16 @@ is the sampled-token fetch.
 The KV cache stays in the policy's low-bit format end-to-end (the paper's
 attention pipeline); weights may be offline-packed (GEMM pipeline) by
 calling ``quantize_params`` before construction.
+
+Observability costs no option.  ``step()`` runs inside a
+``jax.profiler.TraceAnnotation`` named ``engine.step`` with one child per
+phase (``engine.admit``, ``engine.plan``, ``engine.feed``,
+``engine.dispatch``, ``engine.wait``, ``engine.emit``), which records only
+while a profiler session is active; the jitted step names its device
+stages with ``jax.named_scope`` (``sample`` here, the model's in its
+``decode_step``).  ``Engine.stats`` (:class:`EngineStats`) counts the
+work as it happens, and every output of an admitted request carries its
+``queue_time``.
 """
 from __future__ import annotations
 
@@ -92,6 +102,7 @@ import numpy as np
 from repro.core import kvcache as KV
 from repro.core import paged_kvcache as PKV
 from repro.core.precision import PrecisionPolicy
+from repro.kernels import ops as kops
 from repro.models import common as C
 from repro.models.registry import Model, build
 
@@ -126,6 +137,29 @@ def quantize_params(params, policy: PrecisionPolicy):
         else:
             out.append(p)
     return treedef.unflatten(out)
+
+
+#: the engine's host phases, on the profiler's clock (a no-op without a
+#: profiler session)
+_span = jax.profiler.TraceAnnotation
+
+
+@dataclasses.dataclass
+class EngineStats:
+    """Counts of the work ``Engine.step`` did, since construction."""
+
+    #: model steps run, by step width (tokens fed per slot)
+    steps_by_width: Dict[int, int] = dataclasses.field(default_factory=dict)
+    #: rows the steps computed (``n_slots × width`` each), and the rows
+    #: among them that fed a stream token
+    rows: int = 0
+    valid_rows: int = 0
+    #: block-table row uploads to the device (``_map_slot_blocks`` calls)
+    table_uploads: int = 0
+    #: paged attention kernel, per layer: (slot, block) grid cells
+    #: dispatched, and the cells that hold a running slot's live context
+    attn_cells: int = 0
+    attn_live_cells: int = 0
 
 
 def _slot_insert(batch_cache, slot_cache, slot: jax.Array):
@@ -252,6 +286,7 @@ class Engine:
             self._cow_copy = jax.jit(PKV.copy_block)
         self.t0 = time.perf_counter()
         self.iteration = 0
+        self.stats = EngineStats()
 
     # -- jit'd inner functions -------------------------------------------
 
@@ -280,7 +315,8 @@ class Engine:
             kw["valid"] = valid
         logits, cache = self.model.decode_step(params, self.policy, tokens,
                                                cache, pos, **kw)
-        nxt = S.sample(S.slot_keys(seeds, steps), logits, temp, top_k)
+        with jax.named_scope("sample"):
+            nxt = S.sample(S.slot_keys(seeds, steps), logits, temp, top_k)
         return nxt, cache
 
     # -- public API --------------------------------------------------------
@@ -454,6 +490,7 @@ class Engine:
         return True
 
     def _map_slot_blocks(self, slot: int, blocks: List[int]) -> None:
+        self.stats.table_uploads += 1
         row = jnp.full((self.blocks_per_slot,), self.n_blocks, jnp.int32)
         if blocks:
             row = row.at[:len(blocks)].set(jnp.asarray(blocks, jnp.int32))
@@ -672,12 +709,38 @@ class Engine:
         finished requests carry ``finish_reason`` and final timing
         metrics.  Growth mode may additionally grow/preempt before the
         step (preempted requests emit nothing until recovered)."""
-        self.iteration += 1
-        for req in self.scheduler.admit():
-            self._admit(req)
-        running = self.scheduler.running()
-        if not running:
-            return []
+        with _span("engine.step"):
+            self.iteration += 1
+            with _span("engine.admit"):
+                now = self.now()
+                for req in self.scheduler.admit():
+                    if req.admit_time is None:
+                        req.admit_time = now
+                    self._admit(req)
+            running = self.scheduler.running()
+            if not running:
+                return []
+            with _span("engine.plan"):
+                running, t_step, valids = self._plan(running)
+            if not running:
+                return []
+            with _span("engine.feed"):
+                tokens, pos, valid, seeds, steps, temp, top_k, max_live = \
+                    self._feed(running, t_step, valids)
+            with _span("engine.dispatch"):
+                nxt, self.cache = self._step(self.params, tokens, self.cache,
+                                             pos, valid, seeds, steps, temp,
+                                             top_k, max_live=max_live)
+                t = self.now()
+            with _span("engine.wait"):
+                nxt_host = np.asarray(jax.device_get(nxt))
+            with _span("engine.emit"):
+                return self._emit(running, valids, nxt_host, t)
+
+    def _plan(self, running: List[Request]):
+        """The step's width and per-request feed counts; growth mode
+        first maps (or preempts for) the blocks the step will write.
+        Returns (surviving running set, width, {rid: valid})."""
         chunk = self.prefill_chunk if self._chunked else 1
         t_step, valids = self.scheduler.plan(chunk)
         if self._growth:
@@ -688,10 +751,14 @@ class Engine:
             # shrinks the running set, so re-plan (the step may narrow
             # back to width 1).
             running = self._grow_for_step(running, valids)
-            if not running:
-                return []
-            t_step, valids = self.scheduler.plan(chunk)
+            if running:
+                t_step, valids = self.scheduler.plan(chunk)
+        return running, t_step, valids
 
+    def _feed(self, running: List[Request], t_step: int,
+              valids: Dict[int, int]):
+        """The jitted step's per-slot inputs and its live-context bound;
+        counts the step in :attr:`stats`."""
         # per-slot feed + sampling vectors, assembled host-side (numpy)
         # and handed to the jit'd step as single transfers — no
         # per-request scatter dispatches in the hot loop.  Idle slots
@@ -718,12 +785,24 @@ class Engine:
         # paged: bound the kernel's grid (and its HBM traffic) by the
         # batch's live-context high-water mark, not worst-case max_seq
         max_live = self._live_bucket(running) if self._paged else None
-        nxt, self.cache = self._step(self.params, jnp.asarray(tokens),
-                                     self.cache, jnp.asarray(pos),
-                                     jnp.asarray(valid), seeds, steps,
-                                     temp, top_k, max_live=max_live)
-        t = self.now()
-        nxt_host = np.asarray(jax.device_get(nxt))
+        st = self.stats
+        st.steps_by_width[t_step] = st.steps_by_width.get(t_step, 0) + 1
+        st.rows += self.n_slots * t_step
+        st.valid_rows += int(valid.sum())
+        if self._paged and self._attn_kernels:
+            bs = self.block_size
+            n_s = min(kops.grid_blocks(max_live, t_step, bs),
+                      self.blocks_per_slot)
+            st.attn_cells += self.n_slots * n_s
+            st.attn_live_cells += sum(
+                min(-(-(r.pos + valids[r.rid]) // bs), n_s) for r in running)
+        return (jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(valid),
+                seeds, steps, temp, top_k, max_live)
+
+    def _emit(self, running: List[Request], valids: Dict[int, int],
+              nxt_host: np.ndarray, t: float) -> List[RequestOutput]:
+        """Advance every fed request's cursor; emit, retire and reclaim
+        those that consumed their last unfed stream token."""
         outputs: List[RequestOutput] = []
         for r in running:
             r.pos += valids[r.rid]

@@ -108,7 +108,9 @@ class RequestOutput:
     one-chunk recovery path keeps this O(produced / prefill_chunk) per
     preemption instead of O(produced)), and ``recovery_time`` is the
     total wall-clock seconds between each eviction and the request's
-    next emission.
+    next emission.  ``queue_time`` is the seconds the request waited
+    between arrival and its first admission to a slot (None until it is
+    admitted; a preemption does not move it).
     """
 
     rid: int
@@ -121,6 +123,7 @@ class RequestOutput:
     num_preemptions: int = 0
     replay_iterations: int = 0
     recovery_time: float = 0.0
+    queue_time: Optional[float] = None
 
     # final metrics (populated on the finished output) -------------------
     ttft: Optional[float] = None        # first-token latency (s)
@@ -152,6 +155,9 @@ class Request:
     pos: int = 0
     output: List[int] = dataclasses.field(default_factory=list)
     finish_reason: Optional[FinishReason] = None
+    #: first admission to a slot (engine clock); re-admission after a
+    #: preemption leaves it
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None    # TTFT measurement
     finish_time: Optional[float] = None
     #: prompt tokens served from the prefix cache (reported on outputs)
@@ -186,6 +192,13 @@ class Request:
         return self.first_token_time - self.arrival_time
 
     @property
+    def queue_time(self) -> Optional[float]:
+        """Seconds from arrival to first admission (None until then)."""
+        if self.admit_time is None:
+            return None
+        return self.admit_time - self.arrival_time
+
+    @property
     def latency(self) -> Optional[float]:
         """End-to-end latency in seconds (None until finished)."""
         if self.finish_time is None:
@@ -209,5 +222,6 @@ class Request:
             num_preemptions=self.num_preemptions,
             replay_iterations=self.replay_iterations,
             recovery_time=self.recovery_time,
+            queue_time=self.queue_time,
             ttft=self.ttft if done else None,
             latency=self.latency if done else None)

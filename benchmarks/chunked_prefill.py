@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_reduced
+from repro.core import kvcache as KV
 from repro.core import paged_kvcache as PKV
 from repro.core.precision import get_policy
 from repro.serving import Engine, EngineConfig, SamplingParams
@@ -87,10 +88,13 @@ def _splice_cost(plen: int, slots: int, max_seq: int):
     hkv, d = cfg.n_kv_heads, cfg.d_model // cfg.n_heads
     bps = max_seq // BLOCK
     nb = slots * bps
-    pool = PKV.init_paged(slots, nb, BLOCK, hkv, d, spec,
-                          blocks_per_slot=bps)
-    leaves = {"k": pool.k, "k_scale": pool.k_scale,
-              "v": pool.v, "v_scale": pool.v_scale}
+    # the staged path's pool rows: (block, token, head, depth)
+    kv = (nb, BLOCK, hkv, KV.store_dim(d, spec))
+    sc = (nb, BLOCK, hkv, 1)
+    leaves = {"k": jnp.zeros(kv, spec.dtype),
+              "k_scale": jnp.ones(sc, jnp.float32),
+              "v": jnp.zeros(kv, spec.dtype),
+              "v_scale": jnp.ones(sc, jnp.float32)}
     # block-scattered destinations, like a live allocator's mapping
     rng = np.random.default_rng(3)
     blocks = rng.permutation(nb)[:PKV.blocks_needed(plen, BLOCK)]

@@ -60,26 +60,20 @@ class CompileClock:
             self.secs += duration
 
 
-def _paged_twin(dense, bs: int, seed: int):
-    """A PagedKVCache holding ``dense``'s logical contents in shuffled
-    pool blocks."""
+def _paged_twin(k, v, spec, bs: int, seed: int):
+    """A paged pool holding what ``KV.append(..., k, v, 0, spec)`` holds,
+    written through ``append_paged`` into shuffled pool blocks."""
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.core.paged_kvcache import PagedKVCache
-    B, S = dense.k.shape[:2]
+    from repro.core import paged_kvcache as PKV
+    B, S, H, D = k.shape
     bps = S // bs
     perm = np.random.default_rng(seed).permutation(B * bps)
-    inv = np.argsort(perm)
-
-    def pool(a):    # (B, S, ...) → (B*bps, bs, ...) with block i at perm[i]
-        return a.reshape(B * bps, bs, *a.shape[2:])[inv]
-
-    return PagedKVCache(
-        k=pool(dense.k), v=pool(dense.v), k_scale=pool(dense.k_scale),
-        v_scale=pool(dense.v_scale),
-        block_table=jnp.asarray(perm.reshape(B, bps), jnp.int32),
-        length=dense.length)
+    paged = PKV.init_paged(B, B * bps, bs, H, D, spec, blocks_per_slot=bps)
+    paged = dataclasses.replace(
+        paged, block_table=jnp.asarray(perm.reshape(B, bps), jnp.int32))
+    return PKV.append_paged(paged, k, v, jnp.zeros((B,), jnp.int32), spec)
 
 
 def kernel_phase(cfg, *, B=4, S=512, bs=16) -> None:
@@ -98,10 +92,9 @@ def kernel_phase(cfg, *, B=4, S=512, bs=16) -> None:
     for fmt, tol in ATTN_TOL.items():
         spec = get_policy(f"w4a16{fmt}").kv
         kv = jax.random.normal(key, (2, B, S, Hkv, D), jnp.float32)
-        dense = KV.append(KV.init_cache(B, S, Hkv, D, spec),
-                          kv[0].astype(jnp.bfloat16),
-                          kv[1].astype(jnp.bfloat16), 0, spec)
-        paged = _paged_twin(dense, bs, SEED)
+        k, v = kv[0].astype(jnp.bfloat16), kv[1].astype(jnp.bfloat16)
+        dense = KV.append(KV.init_cache(B, S, Hkv, D, spec), k, v, 0, spec)
+        paged = _paged_twin(k, v, spec, bs, SEED)
         for T in (1, SERVE["prefill_chunk"]):
             q = jax.random.normal(jax.random.fold_in(key, T), (B, T, H, D),
                                   jnp.float32).astype(jnp.bfloat16)
@@ -172,11 +165,9 @@ def _first_chunk_logits(engine, prompts):
     cache = engine.model.init_paged_cache(
         engine.policy, B, engine.n_blocks, engine.block_size,
         engine.blocks_per_slot)
-    L = cache.block_table.shape[0]
     tbl = jnp.arange(B * engine.blocks_per_slot, dtype=jnp.int32).reshape(
-        1, B, engine.blocks_per_slot)
-    cache = dataclasses.replace(cache,
-                                block_table=jnp.broadcast_to(tbl, (L,) + tbl.shape[1:]))
+        B, engine.blocks_per_slot)
+    cache = dataclasses.replace(cache, block_table=tbl)
     tokens = jnp.asarray([p[:T] for p in prompts[:B]], jnp.int32)
     fn = jax.jit(lambda p, tok, c: engine.model.decode_step(
         p, engine.policy, tok, c, jnp.zeros((B,), jnp.int32),

@@ -9,16 +9,41 @@ in fixed-size *blocks* instead:
 
 Layout
 ------
-* **Block pool**: ``k/v`` are ``(n_blocks, block_size, H, Dstore)`` with
-  per-(token, head) scales ``(n_blocks, block_size, H, 1)`` — the same
-  quantized layout as the dense cache (head_dim minor / lane axis; kv4
+* **Block pool**: ``k/v`` are ``([L,] n_blocks, block_size, KVL)`` —
+  each token's heads side by side on the lane axis, head ``h`` on lanes
+  ``[h*Dstore, (h+1)*Dstore)``, ``KVL = H * Dstore`` rounded up to a
+  multiple of 128 — and the per-(token, head) scales are ``([L,]
+  n_blocks, 1, SL)`` f32, token ``t`` of head ``h`` on lane
+  ``h*block_size + t``, ``SL = H * block_size`` rounded up to 128.
+  Values are quantized exactly as in the dense cache (kv4
   nibble-packed 2-per-int8, ``Dstore = head_dim // 2``), so every
-  ``FormatSpec`` works unchanged and dequantization stays lane-aligned.
-* **Block table**: ``(n_slots, blocks_per_slot)`` int32.  Entry ``j`` of
+  ``FormatSpec`` works unchanged; only where the bytes live differs.
+  Pad lanes are never written or read.
+* **One layout for the whole step**: a TPU tiles an array's two minor
+  dims in (8, 128) words, and both Pallas kernels that touch the pool —
+  the attention reader (``kernels/paged_kvattn.py``) and the in-place
+  writer (``kernels/pool_write.py``) — need those tiles row-major.
+  Rounding the lane dim up to a multiple of 128 makes row-major the
+  layout the TPU compiler itself picks for the array, so the pool is
+  created, passed into and out of every jit, and read and written by
+  the kernels in that one layout, and nothing relayouts or copies it.
+  The shape follows from ``(H, Dstore, block_size)`` alone.  With any
+  other lane width (kv8 at H = 5, Dstore = 64: 320) the compiler stores
+  the pool block-minor, which suits an XLA scatter but not the kernels,
+  and every layer then paid pool-sized relayout copies; pinning the
+  row-major layout with ``jax.experimental.layout`` instead does not
+  survive JAX's persistent compilation cache (an executable loaded from
+  it expects the compiler's layout).  The engine's pool is stacked over
+  layers, ``(L, ...)``, and the layer scan carries it whole instead of
+  slicing it: the cache's scalar ``layer`` names the layer that
+  :func:`append_paged`, the attention kernel and :func:`gather_view`
+  address (0 for a per-layer pool, whose leaves lack the ``L`` axis).
+* **Block table**: ``(n_slots, blocks_per_slot)`` int32, shared by every
+  layer.  Entry ``j`` of
   slot ``b``'s row names the pool block holding logical positions
   ``[j*block_size, (j+1)*block_size)`` of that slot.  Unmapped entries hold
-  the sentinel ``n_blocks`` (one past the pool): scatter-writes through a
-  sentinel are dropped, gather-reads clamp to an arbitrary (finite) pool
+  the sentinel ``n_blocks`` (one past the pool): writes through a
+  sentinel are dropped, reads clamp to an arbitrary (finite) pool
   element — safe because every position at or beyond a slot's write
   frontier is masked by the causal ``kpos <= pos`` attention mask.
 * **Allocator**: `BlockAllocator` is plain host-side Python (the engine
@@ -30,11 +55,9 @@ Layout
   are retained in an LRU "cached" state and revived on a prefix hit or
   evicted when the free list runs dry (DESIGN.md §5.2).
 
-The whole cache is a registered-dataclass pytree, so the model layer can
-``jax.lax.scan`` over an ``(L, ...)``-stacked instance and the launch layer
-can shard the pool axes like any other array.  All properties (block_size,
-n_blocks, ...) are derived from leaf shapes and are only meaningful on a
-per-layer (unstacked) instance.
+The whole cache is a registered-dataclass pytree; its properties
+(block_size, n_blocks, ...) are derived from leaf shapes and hold for
+per-layer and stacked instances alike.
 
 Equivalence contract (locked down by tests/test_paged_kvcache.py):
 ``gather_view(append_paged(...))`` returns a dense ``KVCache`` view whose
@@ -62,16 +85,16 @@ from .precision import FormatSpec
 class PagedKVCache:
     """Block-pooled quantized KV storage plus per-slot block tables.
 
-    A registered-dataclass pytree: the model layer scans an
-    ``(L, ...)``-stacked instance, the launch layer shards the pool axes.
-    Shape-derived properties are meaningful on per-layer (unstacked)
-    instances only — see the module docstring for the layout contract.
+    A registered-dataclass pytree whose pool leaves are per-layer or
+    stacked over ``L`` layers (the engine's); ``layer`` names the layer a
+    stacked pool's readers and writer address.  See the module docstring
+    for the layout contract.
     """
 
-    k: jax.Array            # (n_blocks, block_size, H, Dstore)
-    v: jax.Array            # (n_blocks, block_size, H, Dstore)
-    k_scale: jax.Array      # (n_blocks, block_size, H, 1) f32
-    v_scale: jax.Array      # (n_blocks, block_size, H, 1) f32
+    k: jax.Array            # ([L,] n_blocks, block_size, KVL)
+    v: jax.Array            # ([L,] n_blocks, block_size, KVL)
+    k_scale: jax.Array      # ([L,] n_blocks, 1, SL) f32
+    v_scale: jax.Array      # ([L,] n_blocks, 1, SL) f32
     block_table: jax.Array  # (n_slots, blocks_per_slot) int32; n_blocks = unmapped
     #: (n_slots,) int32 — advisory append counter, incremented for every
     #: slot on each append exactly like the dense cache's ``length`` (so
@@ -79,17 +102,24 @@ class PagedKVCache:
     #: ``positions`` are the authoritative per-slot frontier; attention
     #: masks by position, never by this field.
     length: jax.Array
+    #: () int32 — the layer of a stacked pool that appends, attention and
+    #: ``gather_view`` address (set by the model's layer scan; 0 and
+    #: unused for a per-layer pool)
+    layer: jax.Array
+    #: KV heads per token and stored values per head (static: the lane
+    #: widths above are padded, so the shapes do not carry them)
+    kv_heads: int = dataclasses.field(metadata=dict(static=True))
+    store_dim: int = dataclasses.field(metadata=dict(static=True))
 
-    # Shape-derived metadata — valid on per-layer (unstacked) instances.
     @property
     def n_blocks(self) -> int:
         """Pool blocks (the block-table sentinel value is ``n_blocks``)."""
-        return self.k.shape[0]
+        return self.k.shape[-3]
 
     @property
     def block_size(self) -> int:
         """Tokens per pool block."""
-        return self.k.shape[1]
+        return self.k.shape[-2]
 
     @property
     def n_slots(self) -> int:
@@ -105,6 +135,11 @@ class PagedKVCache:
     def max_context(self) -> int:
         """Longest per-slot context the block table can map."""
         return self.blocks_per_slot * self.block_size
+
+
+def lanes(n: int) -> int:
+    """``n`` rounded up to whole 128-lane tiles: the pool's lane widths."""
+    return -(-int(n) // 128) * 128
 
 
 class OutOfBlocksError(RuntimeError):
@@ -343,52 +378,68 @@ def blocks_needed(n_tokens: int, block_size: int) -> int:
 
 def init_paged(n_slots: int, n_blocks: int, block_size: int, kv_heads: int,
                head_dim: int, spec: FormatSpec,
-               blocks_per_slot: Optional[int] = None) -> PagedKVCache:
-    """Zero-initialized pool with an all-sentinel block table."""
+               blocks_per_slot: Optional[int] = None,
+               n_layers: Optional[int] = None) -> PagedKVCache:
+    """Zero-initialized pool with an all-sentinel block table.
+    ``n_layers`` stacks the pool over that many layers (the engine's
+    cache); ``None`` gives a per-layer pool."""
     ds = KV.store_dim(head_dim, spec)
     bps = blocks_per_slot if blocks_per_slot is not None else \
         blocks_needed(n_blocks * block_size, block_size)
-    shape = (n_blocks, block_size, kv_heads, ds)
+    lead = () if n_layers is None else (n_layers,)
+    kv = lead + (n_blocks, block_size, lanes(kv_heads * ds))
+    sc = lead + (n_blocks, 1, lanes(kv_heads * block_size))
     return PagedKVCache(
-        k=jnp.zeros(shape, spec.dtype),
-        v=jnp.zeros(shape, spec.dtype),
-        k_scale=jnp.ones((n_blocks, block_size, kv_heads, 1), jnp.float32),
-        v_scale=jnp.ones((n_blocks, block_size, kv_heads, 1), jnp.float32),
+        k=jnp.zeros(kv, spec.dtype), v=jnp.zeros(kv, spec.dtype),
+        k_scale=jnp.ones(sc, jnp.float32), v_scale=jnp.ones(sc, jnp.float32),
         block_table=jnp.full((n_slots, bps), n_blocks, jnp.int32),
         length=jnp.zeros((n_slots,), jnp.int32),
-    )
+        layer=jnp.zeros((), jnp.int32), kv_heads=kv_heads, store_dim=ds)
 
 
-def _flat_indices(cache: PagedKVCache, tok: jax.Array) -> jax.Array:
-    """Logical per-slot token positions (B, T) → flat pool indices (B, T).
-
-    Positions mapped by a sentinel (or beyond the table) come back as
-    ``n_blocks * block_size`` — out of range for the flattened pool, so
-    scatter drops them and gather (mode="clip") clamps to a finite value.
-    """
-    bs = cache.block_size
-    bidx = tok // bs                                       # (B, T)
-    safe = jnp.clip(bidx, 0, cache.blocks_per_slot - 1)
-    blk = jnp.take_along_axis(cache.block_table, safe, axis=1)
-    blk = jnp.where(bidx < cache.blocks_per_slot, blk, cache.n_blocks)
-    oob = jnp.int32(cache.n_blocks * bs)
-    return jnp.where(blk < cache.n_blocks, blk * bs + tok % bs, oob)
+def _stacked(a: jax.Array) -> jax.Array:
+    """A pool leaf with its layer axis: per-layer leaves gain ``L = 1``."""
+    return a if a.ndim == 4 else a[None]
 
 
-def _pool_scatter(pool: jax.Array, flat: jax.Array,
-                  vals: jax.Array) -> jax.Array:
-    """Write vals (N, H, d) at flat indices (N,) into (nb, bs, H, d) pool."""
-    nb, bs = pool.shape[:2]
-    p = pool.reshape((nb * bs,) + pool.shape[2:])
-    p = p.at[flat].set(vals, mode="drop")
-    return p.reshape(pool.shape)
+def _write_plan(cache: PagedKVCache, pos: jax.Array, n: jax.Array, T: int):
+    """Where a ``T``-row append lands: one step per (slot, pool block its
+    rows can touch), ``J`` per slot.
+
+    Returns ``blk`` (B*J,), the pool block of each step; ``lo``/``hi``
+    (B*J,), the block rows it overwrites (``lo == hi``: none); and
+    ``first`` (B, J), the chunk row that lands on each block's row 0.
+    Slot ``b`` writes rows ``t < n[b]`` at positions ``pos[b] + t``; rows
+    past the table, through a sentinel entry, or of an idle slot
+    (``n == 0``) write nothing.  A step that writes nothing names the
+    block of the last step that does (the first one that does, before
+    it), so every block the kernel visits is named by one run of
+    consecutive steps — the condition ``kernels/pool_write.py`` needs to
+    read each block once and write it back once."""
+    bs, bps, nb = cache.block_size, cache.blocks_per_slot, cache.n_blocks
+    J = (T + bs - 2) // bs + 1            # most blocks T rows can touch
+    lblk = pos[:, None] // bs + jnp.arange(J, dtype=jnp.int32)[None]
+    first = lblk * bs - pos[:, None]
+    lo = jnp.clip(-first, 0, bs)
+    hi = jnp.clip(n[:, None] - first, 0, bs)
+    blk = jnp.take_along_axis(cache.block_table,
+                              jnp.clip(lblk, 0, bps - 1), axis=1)
+    write = ((lblk < bps) & (blk < nb) & (hi > lo)).reshape(-1)
+    step = jnp.arange(write.shape[0], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(write, step, -1), axis=0)
+    src = jnp.where(last >= 0, last, jnp.argmax(write).astype(jnp.int32))
+    blk = jnp.minimum(blk.reshape(-1)[src], nb - 1)
+    lo = jnp.where(write, lo.reshape(-1), 0)
+    hi = jnp.where(write, hi.reshape(-1), 0)
+    return blk, lo, hi, first
 
 
 def append_paged(cache: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
                  pos: jax.Array, spec: FormatSpec,
                  valid=None) -> PagedKVCache:
     """Ragged append through the block table: slot ``b`` quantizes and
-    writes its ``T`` new tokens at logical positions ``pos[b] + t``.
+    writes its ``T`` new tokens at logical positions ``pos[b] + t`` of
+    the layer ``cache.layer`` addresses.
 
     k_new/v_new: (B, T, H, D) compute dtype; pos: (B,) int32 (a scalar is
     broadcast).  ``valid`` (optional, (B,) int32) masks the write to each
@@ -396,33 +447,49 @@ def append_paged(cache: PagedKVCache, k_new: jax.Array, v_new: jax.Array,
     frontier in a padded mixed prefill/decode step are *dropped* (they
     would otherwise land in live cells of refcounted shared blocks).
     Same quantization path as the dense cache — values land
-    bit-identical, only the layout differs.
+    bit-identical, only the layout differs.  The rows are written in
+    place by the Pallas writer (``kernels/pool_write.py``), which reads
+    and writes back only the pool blocks they touch.
     """
-    B, T = k_new.shape[:2]
+    from repro.kernels import ops as kops
+    B, T, H, _ = k_new.shape
+    bs = cache.block_size
     kq, ks = Q.quantize_kv(k_new, spec)
     vq, vs = Q.quantize_kv(v_new, spec)
     pos = jnp.asarray(pos, jnp.int32)
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (B,))
-    tok = pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None]   # (B, T)
-    flat = _flat_indices(cache, tok)
-    if valid is not None:
-        flat = jnp.where(
-            jnp.arange(T, dtype=jnp.int32)[None] <
-            jnp.asarray(valid, jnp.int32)[:, None],
-            flat, jnp.int32(cache.n_blocks * cache.block_size))
-    flat = flat.reshape(-1)
-    merge = lambda a: a.reshape((B * T,) + a.shape[2:])
-    return PagedKVCache(
-        k=_pool_scatter(cache.k, flat, merge(kq)),
-        v=_pool_scatter(cache.v, flat, merge(vq)),
-        k_scale=_pool_scatter(cache.k_scale, flat,
-                              merge(ks.astype(jnp.float32))),
-        v_scale=_pool_scatter(cache.v_scale, flat,
-                              merge(vs.astype(jnp.float32))),
-        block_table=cache.block_table,
-        length=cache.length + T,
-    )
+    n = jnp.full((B,), T, jnp.int32) if valid is None else \
+        jnp.clip(jnp.asarray(valid, jnp.int32), 0, T)
+    blk, lo, hi, first = _write_plan(cache, pos, n, T)
+    # each step's new rows laid out as its block: block row r holds chunk
+    # row first + r (clipped; rows outside [lo, hi) are not written)
+    rows = jnp.clip(first[..., None] + jnp.arange(bs, dtype=jnp.int32),
+                    0, T - 1).reshape(B, -1, 1)
+    G = blk.shape[0]
+    kvl, sl = cache.k.shape[-1], cache.k_scale.shape[-1]
+
+    def tiles(x):           # (B, T, H, Ds) → (G, bs, KVL)
+        x = jnp.take_along_axis(x.reshape(B, T, -1), rows, axis=1)
+        x = x.reshape(G, bs, -1)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, kvl - x.shape[-1])))
+
+    def scale_tiles(s):     # (B, T, H, 1) → (G, 1, SL), lane h*bs + r
+        x = jnp.take_along_axis(s.astype(jnp.float32).reshape(B, T, H),
+                                rows, axis=1)
+        x = x.reshape(G, bs, H).transpose(0, 2, 1).reshape(G, 1, H * bs)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, sl - H * bs)))
+
+    out = kops.kv_pool_write(
+        tuple(_stacked(a) for a in (cache.k, cache.k_scale, cache.v,
+                                    cache.v_scale)),
+        (tiles(kq), scale_tiles(ks), tiles(vq), scale_tiles(vs)),
+        cache.layer, blk, lo, hi, kv_lanes=H * cache.store_dim,
+        heads=H)
+    k, k_scale, v, v_scale = (o.reshape(a.shape) for o, a in zip(
+        out, (cache.k, cache.k_scale, cache.v, cache.v_scale)))
+    return dataclasses.replace(cache, k=k, v=v, k_scale=k_scale,
+                               v_scale=v_scale, length=cache.length + T)
 
 
 def live_ctx(cache: PagedKVCache,
@@ -449,17 +516,17 @@ def live_ctx(cache: PagedKVCache,
 def gather_view(cache: PagedKVCache,
                 n_ctx: Optional[int] = None) -> KV.KVCache:
     """Materialize a dense ``(n_slots, n_ctx, H, Dstore)`` view of every
-    slot's logical context by gathering pool blocks through the block
-    tables.
+    slot's logical context in the layer ``cache.layer`` addresses, by
+    gathering pool blocks through the block table.
 
-    This is the glue between paged storage and the existing decode
-    kernels: the view is a plain :class:`KVCache`, so the fused XLA
-    attention and the Pallas decode kernel consume it unchanged.  Unmapped
-    positions clamp to an arbitrary pool element — finite garbage that the
-    caller's causal mask turns into exact zeros.  The view is transient
+    This is the glue between paged storage and the dense attention path:
+    the view is a plain :class:`KVCache` in the dense cache's layout, so
+    the fused XLA attention consumes it unchanged.  Unmapped positions
+    clamp to an arbitrary pool element — finite garbage that the caller's
+    causal mask turns into exact zeros.  The view is transient
     (activation memory); only the pool is resident.
     """
-    bs = cache.block_size
+    bs, H, ds = cache.block_size, cache.kv_heads, cache.store_dim
     n_ctx = cache.max_context if n_ctx is None else n_ctx
     assert n_ctx % bs == 0, (n_ctx, bs)
     nbp = min(n_ctx // bs, cache.blocks_per_slot)
@@ -469,37 +536,43 @@ def gather_view(cache: PagedKVCache,
         .reshape(cache.n_slots, nbp * bs)                  # (B, n_ctx)
     if nbp * bs < n_ctx:   # table shorter than requested view: clamp-pad
         flat = jnp.pad(flat, ((0, 0), (0, n_ctx - nbp * bs)))
-    nb = cache.n_blocks
 
-    def gath(pool):
-        p = pool.reshape((nb * bs,) + pool.shape[2:])
-        out = jnp.take(p, flat.reshape(-1), axis=0, mode="clip")
-        return out.reshape((cache.n_slots, n_ctx) + pool.shape[2:])
+    def gath(rows):        # (nb * bs, H, d) → (B, n_ctx, H, d)
+        out = jnp.take(rows, flat.reshape(-1), axis=0, mode="clip")
+        return out.reshape((cache.n_slots, n_ctx) + rows.shape[1:])
 
-    return KV.KVCache(k=gath(cache.k), v=gath(cache.v),
-                      k_scale=gath(cache.k_scale),
-                      v_scale=gath(cache.v_scale),
-                      length=cache.length)
+    def kv(pool):          # ([L,] nb, bs, KVL) at the addressed layer
+        p = _stacked(pool)[cache.layer][..., :H * ds]
+        return gath(p.reshape(-1, H, ds))
+
+    def scale(pool):       # ([L,] nb, 1, SL) at the addressed layer
+        p = _stacked(pool)[cache.layer][:, 0, :H * bs]
+        return gath(p.reshape(-1, H, bs).transpose(0, 2, 1)
+                    .reshape(-1, H, 1))
+
+    return KV.KVCache(k=kv(cache.k), v=kv(cache.v),
+                      k_scale=scale(cache.k_scale),
+                      v_scale=scale(cache.v_scale), length=cache.length)
 
 
 def copy_block(cache: PagedKVCache, src: jax.Array,
                dst: jax.Array) -> PagedKVCache:
-    """Copy one pool block's K/V/scale bytes ``src`` → ``dst``.
+    """Copy one pool block's K/V/scale bytes ``src`` → ``dst``, in every
+    layer of a stacked pool.
 
     The device half of copy-on-write materialization (DESIGN.md §5.2):
     when a slot would append into a *shared* block, the engine allocates
     a private ``dst``, copies the shared block's already-quantized bytes
     (no requantization — COW twins stay bit-identical to a cold prefill),
     and maps ``dst`` into the slot's table instead.  Works on per-layer
-    and ``(L, ...)``-stacked caches alike: the block axis is located
-    relative to the trailing ``(block, token, head, depth)`` layout, so
-    one jit covers both.  Tables and lengths are untouched.
+    and stacked caches alike (the block axis is third from the minor
+    end).  Tables and lengths are untouched.
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
 
     def cp(pool):
-        ax = pool.ndim - 4          # (..., n_blocks, block_size, H, d)
+        ax = pool.ndim - 3          # ([L,] n_blocks, ·, ·)
         val = jax.lax.dynamic_index_in_dim(pool, src, axis=ax,
                                            keepdims=True)
         return jax.lax.dynamic_update_slice_in_dim(pool, val, dst, axis=ax)

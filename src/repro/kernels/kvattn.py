@@ -120,24 +120,24 @@ def flash_init(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def flash_heads_update(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos, window,
-                       base, m_ref, l_ref, acc_ref, *, rep, d, packed,
-                       kv_is_float):
+def flash_heads_update(q_ref, head_tiles, pos, window, base, m_ref, l_ref,
+                       acc_ref, *, rep, d, packed, kv_is_float):
     """:func:`flash_block_update` for every KV head of one block.
 
-    One grid step holds all ``Hkv`` heads of a block: the K/V tiles are
-    ``(1, bs, Hkv, Dstore)`` and the scales ``(1, bs, Hkv, 1)``, whose two
-    minor dimensions are the full array's — the TPU tiling rule that a
-    one-head ``(bs, 1, Dstore)`` tile breaks whenever ``Hkv`` is not 1.
+    One grid step holds all ``Hkv`` heads of a block; ``head_tiles(h)``
+    returns head ``h``'s ``(bs, Dstore)`` K tile, ``(bs, 1)`` K scales,
+    and the same for V, sliced from whatever tiles the kernel's layout
+    DMA'd (the dense kernel's ``(1, bs, Hkv, Dstore)`` slab tile, the
+    paged kernel's ``(bs, KVL)`` pool block).
     q rows are (token, group) pairs in token-major order (r = t*rep + g):
     row r's causal frontier is ``pos + r // rep``.  T == 1 keeps every
     row at ``pos`` — bitwise the single-token decode."""
     R = m_ref.shape[1]
     qpos = pos + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // rep
     for h in range(m_ref.shape[0]):
+        kt, ks, vt, vs = head_tiles(h)
         flash_block_update(
-            q_ref[0, h], k_ref[0, :, h], ks_ref[0, :, h], v_ref[0, :, h],
-            vs_ref[0, :, h], qpos, window, base,
+            q_ref[0, h], kt, ks, vt, vs, qpos, window, base,
             m_ref.at[h], l_ref.at[h], acc_ref.at[h],
             d=d, packed=packed, kv_is_float=kv_is_float)
 
@@ -166,9 +166,10 @@ def _kvattn_kernel(pos_ref, win_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
         flash_init(m_ref, l_ref, acc_ref)
 
     flash_heads_update(
-        q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref[b], win_ref[0],
-        s_blk * block_s, m_ref, l_ref, acc_ref, rep=rep, d=d,
-        packed=packed, kv_is_float=kv_is_float)
+        q_ref, lambda h: (k_ref[0, :, h], ks_ref[0, :, h], v_ref[0, :, h],
+                          vs_ref[0, :, h]),
+        pos_ref[b], win_ref[0], s_blk * block_s, m_ref, l_ref, acc_ref,
+        rep=rep, d=d, packed=packed, kv_is_float=kv_is_float)
 
     @pl.when(s_blk == n_s - 1)
     def _store():

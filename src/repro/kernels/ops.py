@@ -19,6 +19,7 @@ from repro.core.precision import FormatSpec, PrecisionPolicy
 from . import kvattn as _kvattn
 from . import mpgemm as _mpgemm
 from . import paged_kvattn as _pkvattn
+from . import pool_write as _pool_write
 
 INTERPRET = jax.default_backend() != "tpu"
 
@@ -161,8 +162,9 @@ def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
     """Paged decode/chunked-prefill attention with **in-kernel**
     block-table indirection.
 
-    q: (B, T, H, D); ``cache`` is a per-layer (unstacked) PagedKVCache
-    whose block table maps each of the B slots' logical contexts; ``pos``
+    q: (B, T, H, D); ``cache`` is a PagedKVCache (per-layer, or stacked
+    and read at ``cache.layer``) whose block table maps each of the B
+    slots' logical contexts; ``pos``
     is the per-slot *first*-query-token position (token t attends through
     ``pos + t``).  No dense view is ever materialized: the kernel
     scalar-prefetches the table and DMAs K/V/scale tiles block-by-block
@@ -174,7 +176,7 @@ def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
     (sentinel) table entries are clamped to a real pool block and zeroed
     exactly by the kernel's ``kpos <= pos`` mask."""
     B, T, H, D = q.shape
-    Hkv = cache.k.shape[2]
+    Hkv = cache.kv_heads
     rep = H // Hkv
     qg, rk = _group_rows(q, Hkv, rep)       # adaptive head alignment (§4.2)
     n_live = None
@@ -184,6 +186,15 @@ def kvattn_decode_paged(q: jax.Array, cache: PagedKVCache, spec: FormatSpec,
         qg.astype(jnp.bfloat16),
         cache.k, cache.k_scale, cache.v, cache.v_scale,
         cache.block_table, _norm_pos(pos, B), _norm_window(window),
-        packed=spec.packed, kv_is_float=spec.is_float,
+        cache.layer, packed=spec.packed, kv_is_float=spec.is_float,
         n_live_blocks=n_live, rep=rk, interpret=INTERPRET)
     return _ungroup_rows(out, B, T, Hkv, rep, rk, D).astype(q.dtype)
+
+
+def kv_pool_write(pool, new, layer, blk, lo, hi, *, kv_lanes, heads):
+    """In-place write of new KV rows into pool blocks (see
+    kernels/pool_write.py; the plan comes from
+    ``core.paged_kvcache.append_paged``)."""
+    return _pool_write.kv_pool_write(pool, new, layer, blk, lo, hi,
+                                     kv_lanes=kv_lanes, heads=heads,
+                                     interpret=INTERPRET)

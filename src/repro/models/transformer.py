@@ -8,6 +8,7 @@ bf16 arrays or PackedWeight), KV cache quantized per PrecisionPolicy.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -193,16 +194,14 @@ def cache_spec(cfg: ModelConfig, policy: PrecisionPolicy, batch: int,
 def init_paged_cache(cfg: ModelConfig, policy: PrecisionPolicy, n_slots: int,
                      n_blocks: int, block_size: int,
                      blocks_per_slot: int) -> PKV.PagedKVCache:
-    """Per-layer block pools stacked (L, n_blocks, block_size, H, Ds).
-
-    The block table is replicated across layers (a logical block occupies
-    the same pool index in every layer's pool) so the stacked cache scans
-    over layers exactly like the dense cache; the replication is int32 and
-    negligible next to the pools."""
-    f = jax.vmap(lambda _: PKV.init_paged(
-        n_slots, n_blocks, block_size, cfg.n_kv_heads, cfg.hd, policy.kv,
-        blocks_per_slot=blocks_per_slot))
-    return f(jnp.arange(cfg.n_layers))
+    """One block pool stacked over layers, ``(L, n_blocks, block_size,
+    KVL)`` (core/paged_kvcache.py).  A logical
+    block occupies the same pool index in every layer, so one block
+    table serves all of them; :func:`decode_step` carries the pool
+    through its layer scan and addresses one layer at a time."""
+    return PKV.init_paged(n_slots, n_blocks, block_size, cfg.n_kv_heads,
+                          cfg.hd, policy.kv, blocks_per_slot=blocks_per_slot,
+                          n_layers=cfg.n_layers)
 
 
 # ---------------------------------------------------------------------------
@@ -297,13 +296,8 @@ def decode_step(params, cfg: ModelConfig, policy: PrecisionPolicy,
         B, T, d = x.shape
         pos = jnp.asarray(pos, jnp.int32)
         per_slot = pos.ndim == 1
-        # stacked cache leaves carry (L, ...): dense k is (L, B, S, H, Ds),
-        # paged tables are (L, n_slots, blocks_per_slot) mapping bs-token
-        # blocks
-        if paged:
-            n_ctx = cache.block_table.shape[2] * cache.k.shape[2]
-        else:
-            n_ctx = cache.k.shape[2]
+        # stacked dense leaves carry (L, B, S, H, Ds)
+        n_ctx = cache.max_context if paged else cache.k.shape[2]
         if not cfg.use_rope:
             sp = C.sinusoidal_pos(n_ctx, d)
             if per_slot:
@@ -316,8 +310,11 @@ def decode_step(params, cfg: ModelConfig, policy: PrecisionPolicy,
         else:
             rope_pos = jnp.broadcast_to(pos + jnp.arange(T), (B, T))
 
-    def body(xc, sl):
+    def body(carry, sl):
+        xc, pool = carry
         lp, cache_l, idx = sl
+        if paged:
+            cache_l = dataclasses.replace(pool, layer=idx)
         with jax.named_scope("qkv"):
             h = C.rms_norm(xc, lp["ln1"], cfg.norm_eps)
             q, k, v = qkv(h, lp, cfg, policy, impl)
@@ -347,11 +344,22 @@ def decode_step(params, cfg: ModelConfig, policy: PrecisionPolicy,
         with jax.named_scope("ffn"):
             h2 = C.rms_norm(xc, lp["ln2"], cfg.norm_eps)
             xc = xc + ffn(h2, lp, cfg, policy, impl)
-        return xc, cache_l
+        if paged:
+            return (xc, cache_l), None
+        return (xc, None), cache_l
 
+    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     with jax.named_scope("layers"):
-        x, new_cache = jax.lax.scan(
-            body, x, (params["layers"], cache, jnp.arange(cfg.n_layers)))
+        if paged:
+            (x, new_cache), _ = jax.lax.scan(
+                body, (x, dataclasses.replace(cache, layer=layer_ids[0])),
+                (params["layers"], None, layer_ids))
+            # one append per layer is one append of the step
+            new_cache = dataclasses.replace(
+                new_cache, length=cache.length + T, layer=layer_ids[0])
+        else:
+            (x, _), new_cache = jax.lax.scan(
+                body, (x, None), (params["layers"], cache, layer_ids))
     with jax.named_scope("lm_head"):
         if valid is None:
             h_sel = x[:, -1]

@@ -279,11 +279,14 @@ class Engine:
         #: routes a subscribed rid's outputs here so interleaved streams
         #: (each driving step() on its own schedule) never lose tokens
         self._stream_bufs: Dict[int, List[RequestOutput]] = {}
-        self._step = jax.jit(self._step_fn, static_argnames=("max_live",))
+        # a paged step donates the pool, which its kernels then read and
+        # write in place (core/paged_kvcache.py); the dense slab is not
+        self._step = jax.jit(self._step_fn, static_argnames=("max_live",),
+                             donate_argnums=(2,) if self._paged else ())
         self._prefill = jax.jit(self._prefill_fn)
         self._insert = jax.jit(_slot_insert)
         if self.prefix_index is not None:
-            self._cow_copy = jax.jit(PKV.copy_block)
+            self._cow_copy = jax.jit(PKV.copy_block, donate_argnums=0)
         self.t0 = time.perf_counter()
         self.iteration = 0
         self.stats = EngineStats()
@@ -494,7 +497,7 @@ class Engine:
         row = jnp.full((self.blocks_per_slot,), self.n_blocks, jnp.int32)
         if blocks:
             row = row.at[:len(blocks)].set(jnp.asarray(blocks, jnp.int32))
-        tbl = self.cache.block_table.at[:, slot].set(row)
+        tbl = self.cache.block_table.at[slot].set(row)
         self.cache = dataclasses.replace(self.cache, block_table=tbl)
 
     def _register_prefix(self, req: Request) -> None:

@@ -116,6 +116,30 @@ class TestPagedKernelEquivalence:
             np.asarray(out_p, np.float32), np.asarray(ref, np.float32),
             rtol=0.05, atol=0.1 if fmt in ("kv4", "kvfp8") else 0.03)
 
+    @pytest.mark.parametrize("fmt", FMTS)
+    @pytest.mark.parametrize("T", [1, 3])
+    def test_stacked_layer_equals_layer_alone(self, key, fmt, T):
+        """The kernel on a stacked pool at layer l is, bitwise, the
+        kernel on layer l's pool alone — the layers hold different
+        contents under one block table, so reading the wrong layer
+        shows."""
+        layers = [_paired(jax.random.fold_in(key, layer), fmt)
+                  for layer in range(3)]
+        spec, paged = layers[0][0], layers[0][2]
+        stacked = dataclasses.replace(paged, **{
+            n: jnp.stack([getattr(p, n) for _, _, p in layers])
+            for n in ("k", "v", "k_scale", "v_scale")})
+        q = jax.random.normal(jax.random.fold_in(key, 5), (2, T, 4, 32),
+                              jnp.float32).astype(jnp.bfloat16)
+        pos = jnp.array([51, 13], jnp.int32)
+        for layer, (_, _, alone) in enumerate(layers):
+            got = kops.kvattn_decode_paged(
+                q, dataclasses.replace(stacked, layer=jnp.int32(layer)),
+                spec, pos)
+            want = kops.kvattn_decode_paged(q, alone, spec, pos)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                          err_msg=f"{fmt} layer {layer}")
+
     @pytest.mark.parametrize("fmt", ["kv8", "kv4"])
     def test_fused_xla_equivalence(self, key, fmt):
         """Paged kernel ≈ fused XLA on the gathered dense view — the

@@ -550,3 +550,130 @@ def test_paged_pallas_decode_matches_fused(key, fmt):
         np.testing.assert_allclose(
             np.asarray(out_pallas, np.float32),
             np.asarray(out_fused, np.float32), atol=2e-2, rtol=2e-2)
+
+
+# ---------------------------------------------------------------------------
+# Stacked pool: one layer addressed at a time, written in place
+# ---------------------------------------------------------------------------
+
+
+def _stacked(key, fmt, L=3, B=4, H=2, D=16, bs=4, max_seq=16):
+    """A stacked pool whose layers hold different contents: every slot's
+    first 10 positions written, layer by layer, with layer-specific KV;
+    returned with each layer's dense twin.  The table maps slots 0-2
+    (pool blocks shuffled) and leaves slot 3 and slot 2's last block at
+    the sentinel."""
+    spec = _spec(fmt)
+    bps = max_seq // bs
+    n_blocks = B * bps
+    pool = PKV.init_paged(B, n_blocks, bs, H, D, spec, blocks_per_slot=bps,
+                          n_layers=L)
+    order = np.random.default_rng(11).permutation(n_blocks)
+    tbl = np.full((B, bps), n_blocks, np.int32)
+    tbl[:3] = order[:3 * bps].reshape(3, bps)
+    tbl[2, -1] = n_blocks
+    pool = dataclasses.replace(pool, block_table=jnp.asarray(tbl))
+    dense = []
+    for layer in range(L):
+        kk = jax.random.fold_in(key, layer)
+        k = jax.random.normal(kk, (B, 10, H, D), jnp.float32) \
+            .astype(jnp.bfloat16)
+        v = jax.random.normal(jax.random.fold_in(kk, 1), (B, 10, H, D),
+                              jnp.float32).astype(jnp.bfloat16)
+        zero = jnp.zeros((B,), jnp.int32)
+        pool = PKV.append_paged(
+            dataclasses.replace(pool, layer=jnp.int32(layer)), k, v, zero,
+            spec)
+        dense.append(KV.append_per_slot(KV.init_cache(B, max_seq, H, D, spec),
+                                        k, v, zero, spec))
+    return spec, pool, dense
+
+
+def _expected_rows(pool, layer, kq, ks, vq, vs, pos, valid):
+    """The pool leaves after an append at ``layer``, built row by row in
+    numpy: each written (slot, row) lands at its block's row; rows past
+    ``valid``, past the table, or through a sentinel land nowhere."""
+    out = {n: np.array(getattr(pool, n)) for n in ("k", "v", "k_scale",
+                                                  "v_scale")}
+    tbl = np.asarray(pool.block_table)
+    bs, bps, nb = pool.block_size, pool.blocks_per_slot, pool.n_blocks
+    for b in range(tbl.shape[0]):
+        for t in range(int(valid[b])):
+            p = int(pos[b]) + t
+            if p // bs >= bps or tbl[b, p // bs] >= nb:
+                continue
+            blk, r = tbl[b, p // bs], p % bs
+            kvl = kq.shape[2] * kq.shape[3]
+            heads = np.arange(kq.shape[2]) * bs + r
+            out["k"][layer, blk, r, :kvl] = np.asarray(kq[b, t]).reshape(-1)
+            out["v"][layer, blk, r, :kvl] = np.asarray(vq[b, t]).reshape(-1)
+            out["k_scale"][layer, blk, 0, heads] = np.asarray(ks[b, t, :, 0])
+            out["v_scale"][layer, blk, 0, heads] = np.asarray(vs[b, t, :, 0])
+    return out
+
+
+@pytest.mark.parametrize("fmt", ["kv16", "kv8", "kv4", "kvfp8"])
+@pytest.mark.parametrize("T", [1, 6])
+def test_stacked_append_writes_only_addressed_rows(key, fmt, T):
+    """An append at layer 1 of a stacked pool changes exactly the rows it
+    addresses in layer 1, bitwise, and no other byte: ragged positions
+    whose chunk spans block edges, a slot cut short by ``valid``, an idle
+    slot between writing ones (its grid steps repeat a neighbour's
+    block), positions through a sentinel entry and past the table."""
+    from repro.core import quantize as Q
+    spec, pool, _ = _stacked(key, fmt)
+    B, H, D = pool.n_slots, pool.kv_heads, 16
+    k = jax.random.normal(jax.random.fold_in(key, 99), (B, T, H, D),
+                          jnp.float32).astype(jnp.bfloat16)
+    v = -k
+    pos = jnp.array([3, 10, 11, 0], jnp.int32)
+    valid = jnp.array([T, 0, T, T], jnp.int32)     # slot 1 idle
+    if T > 1:
+        valid = valid.at[0].set(T - 2)               # cut short
+    out = PKV.append_paged(dataclasses.replace(pool, layer=jnp.int32(1)),
+                           k, v, pos, spec, valid=valid)
+    kq, ks = Q.quantize_kv(k, spec)
+    vq, vs = Q.quantize_kv(v, spec)
+    want = _expected_rows(pool, 1, kq, ks.astype(jnp.float32), vq,
+                          vs.astype(jnp.float32), pos, valid)
+    for leaf, arr in want.items():
+        np.testing.assert_array_equal(
+            np.asarray(getattr(out, leaf)).view(np.uint8),
+            arr.view(np.uint8), err_msg=f"{fmt} T={T} {leaf}")
+
+
+@pytest.mark.parametrize("fmt", ["kv8", "kv4"])
+def test_stacked_append_with_nothing_to_write_changes_nothing(key, fmt):
+    """Every slot idle (``valid == 0``), or every row through a sentinel:
+    the writer still visits a block, and must write it back as it was."""
+    spec, pool, _ = _stacked(key, fmt)
+    B, H = pool.n_slots, pool.kv_heads
+    k = jnp.ones((B, 3, H, 16), jnp.bfloat16)
+    before = {n: np.asarray(getattr(pool, n)).copy()
+              for n in ("k", "v", "k_scale", "v_scale")}
+    # all idle; then slots 0-1 past the table, 2-3 through sentinels
+    for valid, pos in ((jnp.zeros((B,), jnp.int32), jnp.zeros((B,), jnp.int32)),
+                       (None, jnp.array([16, 16, 12, 0], jnp.int32))):
+        out = PKV.append_paged(
+            dataclasses.replace(pool, layer=jnp.int32(2)), k, k, pos, spec,
+            valid=valid)
+        for leaf, arr in before.items():
+            np.testing.assert_array_equal(
+                np.asarray(getattr(out, leaf)), arr, err_msg=leaf)
+
+
+@pytest.mark.parametrize("fmt", ["kv16", "kv8", "kv4", "kvfp8"])
+def test_stacked_gather_view_matches_dense_per_layer(key, fmt):
+    """``gather_view`` at each layer of a stacked pool equals, at every
+    written position, what the dense ``append_per_slot`` stored for that
+    layer — the layers' contents differ, so a wrong layer shows."""
+    spec, pool, dense = _stacked(key, fmt)
+    for layer, d in enumerate(dense):
+        view = PKV.gather_view(dataclasses.replace(pool,
+                                                   layer=jnp.int32(layer)))
+        for b in range(3):      # the mapped slots; positions 0-9 written
+            for leaf in ("k", "v", "k_scale", "v_scale"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(view, leaf)[b, :10]),
+                    np.asarray(getattr(d, leaf)[b, :10]),
+                    err_msg=f"{fmt} layer {layer} slot {b} {leaf}")

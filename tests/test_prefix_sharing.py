@@ -194,7 +194,7 @@ class TestAllocatorAccounting:
         a = eng.submit(SYS, SamplingParams(max_new_tokens=2))
         eng.step()                                 # full hit: skip = 16
         assert eng._requests[a].prefix_skip == len(SYS) - 1
-        assert int(np.asarray(eng.cache.length)[0, 0]) >= len(SYS) - 1
+        assert int(np.asarray(eng.cache.length)[0]) >= len(SYS) - 1
         _drain(eng)
 
     def test_admission_with_hits_beats_cold_capacity(self):

@@ -5,24 +5,33 @@ d_ff 2560).
 Nothing runs: each case compiles the kernel for one chip of a *described*
 ``v5e:2x2`` topology, which catches what interpret mode cannot — block
 shapes off the (8, 128) tiling, vector layouts and ops Mosaic refuses,
-too much VMEM.  The topology is described inside a module fixture, so
+too much VMEM.  The engine's whole paged step is compiled too, to check
+that it leaves the KV pool where it is: no relayout or copy of it, and
+the pool aliased from the step's input to its output.  The topology is described inside a module fixture, so
 only the worker that runs this file loads the TPU compiler, and the file
 skips where no topology can be described.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro.core.paged_kvcache import lanes
 from repro.kernels import kvattn as KA
 from repro.kernels import mpgemm as MG
 from repro.kernels import paged_kvattn as PKA
+from repro.kernels import pool_write as PW
 from repro.models.common import pick_blocks
 
 HKV, REP, D = 5, 3, 64                  # SmolLM-360M: 15 heads / 5 KV heads
+GLM_HKV, GLM_REP, GLM_D = 2, 16, 128    # ChatGLM3-6B: 32 heads / 2 KV heads
 D_MODEL, D_FF = 960, 2560
 B, BS, CTX, CHUNK = 8, 16, 1024, 32     # engine: slots, block, max_seq, chunk
+L = 2                                   # layers of a stacked pool
 #: KV store dtype, int4-packed, float per format
 FMTS = {"kv16": (jnp.bfloat16, False, True),
         "kv8": (jnp.int8, False, False),
@@ -63,20 +72,61 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
+def _compile_paged(sharding, fmt, T, hkv, rep, d):
+    """The paged kernel over a stacked pool, ``(L, n_blocks, BS, KVL)``
+    K/V and ``(L, n_blocks, 1, SL)`` scales, read at a layer given at
+    run time."""
+    dt, packed, is_float = FMTS[fmt]
+    Ds = d // 2 if packed else d
+    n_blocks = B * CTX // BS
+    kv = ((L, n_blocks, BS, lanes(hkv * Ds)), dt)
+    sc = ((L, n_blocks, 1, lanes(hkv * BS)), jnp.float32)
+    _compile(
+        lambda *a: PKA.paged_kvattn_decode_grouped(
+            *a, packed=packed, kv_is_float=is_float, rep=rep),
+        sharding,
+        ((B, hkv, T * rep, d), jnp.bfloat16), kv, sc, kv, sc,
+        ((B, CTX // BS), jnp.int32), ((B,), jnp.int32), ((1,), jnp.int32),
+        ((), jnp.int32))
+
+
 @pytest.mark.parametrize("T", [1, CHUNK])
 @pytest.mark.parametrize("fmt", sorted(FMTS))
 def test_paged_attention_compiles(one_chip, fmt, T):
-    dt, packed, is_float = FMTS[fmt]
-    Ds = D // 2 if packed else D
-    n_blocks = B * CTX // BS
-    _compile(
-        lambda *a: PKA.paged_kvattn_decode_grouped(
-            *a, packed=packed, kv_is_float=is_float, rep=REP),
+    _compile_paged(one_chip, fmt, T, HKV, REP, D)
+
+
+@pytest.mark.parametrize("T", [1, CHUNK])
+@pytest.mark.parametrize("fmt", sorted(FMTS))
+def test_paged_attention_compiles_at_chatglm3_heads(one_chip, fmt, T):
+    """Two KV heads of 128: the pool's K/V rows are exactly two lane
+    tiles wide."""
+    _compile_paged(one_chip, fmt, T, GLM_HKV, GLM_REP, GLM_D)
+
+
+@pytest.mark.parametrize("heads", [(HKV, D), (GLM_HKV, GLM_D)],
+                         ids=["smollm", "chatglm3"])
+@pytest.mark.parametrize("fmt", sorted(FMTS))
+def test_pool_writer_compiles(one_chip, fmt, heads):
+    """The in-place writer at the engine's chunk width: 3 block steps
+    per slot, every step aliased onto the pool."""
+    hkv, d = heads
+    dt, packed, _ = FMTS[fmt]
+    n_blocks, G = B * CTX // BS, B * 3
+    C, S = lanes(hkv * (d // 2 if packed else d)), lanes(hkv * BS)
+    compiled = _compile(
+        lambda k, ks, v, vs, kn, ksn, vn, vsn, lay, blk, lo, hi:
+        PW.kv_pool_write((k, ks, v, vs), (kn, ksn, vn, vsn), lay, blk, lo,
+                         hi, kv_lanes=hkv * (d // 2 if packed else d),
+                         heads=hkv),
         one_chip,
-        ((B, HKV, T * REP, D), jnp.bfloat16),
-        ((n_blocks, BS, HKV, Ds), dt), ((n_blocks, BS, HKV, 1), jnp.float32),
-        ((n_blocks, BS, HKV, Ds), dt), ((n_blocks, BS, HKV, 1), jnp.float32),
-        ((B, CTX // BS), jnp.int32), ((B,), jnp.int32), ((1,), jnp.int32))
+        ((L, n_blocks, BS, C), dt), ((L, n_blocks, 1, S), jnp.float32),
+        ((L, n_blocks, BS, C), dt), ((L, n_blocks, 1, S), jnp.float32),
+        ((G, BS, C), dt), ((G, 1, S), jnp.float32),
+        ((G, BS, C), dt), ((G, 1, S), jnp.float32),
+        ((), jnp.int32), ((G,), jnp.int32), ((G,), jnp.int32),
+        ((G,), jnp.int32))
+    assert "output_to_operand_aliasing" in compiled.as_text()
 
 
 @pytest.mark.parametrize("T", [1, CHUNK])
@@ -103,3 +153,110 @@ def test_mpgemm_compiles_at_packed_widths(one_chip, K, N):
         one_chip,
         ((B, K), jnp.bfloat16), ((K // bk, N // bn, bk // 2, bn), jnp.int8),
         ((K // bk, N), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# The engine's whole paged step leaves the pool where it is
+# ---------------------------------------------------------------------------
+
+#: HLO element type names of the pool's dtypes
+_HLO_DTYPE = {"int8": "s8", "float32": "f32", "bfloat16": "bf16",
+              "float8_e4m3fn": "f8e4m3fn"}
+_BYTES = {"s8": 1, "u8": 1, "pred": 1, "f8e4m3fn": 1, "bf16": 2, "s16": 2,
+          "f32": 4, "s32": 4, "u32": 4}
+
+
+@pytest.fixture(scope="module")
+def smollm_engines():
+    """The cell's paged engine (32 slots x 1024 tokens, block 16, chunk
+    32) at SmolLM-360M widths with two layers, per KV format."""
+    from repro.configs import get_config
+    from repro.serving import Engine, EngineConfig
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=L)
+    engines = {}
+
+    def get(fmt):
+        if fmt not in engines:
+            engines[fmt] = Engine(EngineConfig(
+                model=cfg, policy=f"w4a16{fmt}", cache_kind="paged",
+                attn_impl="kernel", n_slots=32, max_seq=CTX, block_size=BS,
+                prefill_chunk=CHUNK))
+        return engines[fmt]
+    return get
+
+
+_ARRAY = r"(\w+)\[([\d,]*)\]\{([^}]*)\}"
+
+
+def _relayouts(hlo: str):
+    """(dims, bytes) of the result of every transpose, and of every copy
+    or async copy whose layout differs from its operand's: the copies
+    that move data into another layout.  A copy that only moves an array
+    between memory spaces (``S(n)``, the compiler's prefetches into
+    on-chip memory) keeps its layout and is left out."""
+    layouts, moves = {}, []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        arrays = re.findall(_ARRAY, result)
+        if len(arrays) == 1:
+            layouts[name] = arrays[0]
+        if op in ("copy", "copy-start", "transpose"):
+            src = re.match(r"(%[^,)\s]+)", rest)
+            moves.append((op, arrays[1 if op == "copy-start" else 0],
+                          layouts.get(src.group(1)) if src else None))
+    plain = lambda layout: re.sub(r"S\(\d+\)", "", layout)  # noqa: E731
+    out = []
+    for op, (dt, dims, layout), src in moves:
+        if op != "transpose" and src is not None and \
+                (src[1], plain(src[2])) == (dims, plain(layout)):
+            continue
+        dims = tuple(int(x) for x in dims.split(",") if x)
+        out.append((dims, int(np.prod(dims)) * _BYTES.get(dt, 4)))
+    return out
+
+
+@pytest.mark.parametrize("T", [1, CHUNK])
+@pytest.mark.parametrize("fmt", ["kv8", "kv4"])
+def test_paged_step_moves_no_pool(one_chip, smollm_engines, monkeypatch,
+                                  fmt, T):
+    """The engine's paged step, compiled for the chip in the layout the
+    compiler picks for its arguments, neither relayouts nor copies the
+    KV pool: no copy or transpose into another layout spans the pool's
+    blocks with as many bytes as one layer's K pool, and every pool leaf
+    is aliased from the step's input to its output (the step donates it
+    and the kernels read and write it in place).  Weight relayouts are
+    not the pool's: they are told apart by the block axis."""
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(kops, "INTERPRET", False)
+    eng = smollm_engines(fmt)
+    cache, n_slots = eng.cache, eng.n_slots
+    sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        np.shape(a), a.dtype, sharding=one_chip)
+    z = lambda dt: jax.ShapeDtypeStruct((n_slots,), dt,  # noqa: E731
+                                        sharding=one_chip)
+    hlo = eng._step.lower(
+        jax.tree.map(sds, eng.params),
+        jax.ShapeDtypeStruct((n_slots, T), jnp.int32, sharding=one_chip),
+        jax.tree.map(sds, cache), z(jnp.int32), z(jnp.int32),
+        z(jnp.uint32), z(jnp.int32), z(jnp.float32), z(jnp.int32),
+        max_live=CTX).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+    layer_k = cache.k[0].size * cache.k.dtype.itemsize
+    moved = [(dims, n) for dims, n in _relayouts(hlo)
+             if cache.n_blocks in dims and n >= layer_k]
+    assert not moved, moved
+
+    header = hlo.split("\n", 1)[0]
+    aliased = {int(i) for i in
+               re.findall(r"\((\d+), \{\}, may-alias\)", header)}
+    params = header.split("entry_computation_layout={(", 1)[1]
+    params = re.findall(r"(\w+\[[\d,]*\])\{", params.split(")->", 1)[0])
+    leaves = {f"{_HLO_DTYPE[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+              for a in (cache.k, cache.v, cache.k_scale, cache.v_scale)}
+    pool_params = [i for i, p in enumerate(params) if p in leaves]
+    assert len(pool_params) == 4 and set(pool_params) <= aliased, \
+        (pool_params, aliased)

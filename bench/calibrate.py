@@ -60,7 +60,7 @@ def main(argv=None) -> int:
             engine.params = W.program_params(model, seed,
                                              cell.config["policy"])
         traffic = Traffic(cell.traffic, model.vocab, seed, args.seconds)
-        _, _, n_compiles, _ = H.serve(engine, traffic, args.seconds, None,
+        _, _, n_compiles, _, _ = H.serve(engine, traffic, args.seconds, None,
                                       clock, record=False)
         for r in traffic.reqs:          # unfinished at the close: dropped
             if r.rid is not None and r.done_t is None:

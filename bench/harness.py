@@ -9,6 +9,7 @@ states are read, never changed, in the traced run.
 from __future__ import annotations
 
 import contextlib
+import copy
 import dataclasses
 import gc
 import math
@@ -24,6 +25,7 @@ import numpy as np
 from bench import peaks as PK
 from bench import reference as REF
 from bench import spec as SP
+from bench import stages as ST
 from bench import trace_reduce as TRD
 from bench import weights as W
 from bench.stats import p95, ttft_s
@@ -88,6 +90,7 @@ class Context:
     """What the per-layer readers read (see bench/layer_metrics/)."""
 
     model: W.Model
+    policy: str                    # the configuration's, e.g. "w4a16kv8"
     kv: str                        # KV format of the policy, e.g. "kv8"
     n_slots: int
     seconds: float
@@ -97,18 +100,22 @@ class Context:
     compiles_in_window: int
     trace: Optional[TRD.TraceSummary]
     peaks: Optional[PK.Peaks]
+    stages: Optional[ST.StageSummary]   # the program's stages in the trace
+    stats: Optional[dict]          # growth of ``Engine.stats`` in the window
 
 
 def model_config(config: dict):
+    """The program's ``ModelConfig``, given every key of the
+    configuration's ``model`` entry by name; a key it lacks is an
+    error that names the key."""
     from repro.configs.base import ModelConfig
     m = config["model"]
-    return ModelConfig(
-        name=config["name"], family="dense", n_layers=m["n_layers"],
-        d_model=m["d_model"], n_heads=m["n_heads"],
-        n_kv_heads=m["n_kv_heads"], head_dim=m["head_dim"], d_ff=m["d_ff"],
-        vocab=m["vocab"], rope_theta=m["rope_theta"],
-        rotary_pct=m["rotary_pct"], norm_eps=m["norm_eps"],
-        tie_embeddings=m["tie_embeddings"])
+    unknown = sorted(set(m) - {f.name for f in
+                               dataclasses.fields(ModelConfig)})
+    if unknown:
+        raise ValueError(f"{config['name']}: the program's ModelConfig has "
+                         f"no field {', '.join(unknown)}")
+    return ModelConfig(name=config["name"], family="dense", **m)
 
 
 def build_engine(cell: SP.Cell, seed: int):
@@ -195,12 +202,21 @@ class Recorder:
         return [(p0, r.pos - p0) for r, p0 in snapshot if r.pos > p0]
 
 
+def stats_delta(before, after) -> dict:
+    """How far each of the engine's counters grew from ``before`` to
+    ``after`` (``EngineStats``; a dict of counts by key grows by key)."""
+    a, b = dataclasses.asdict(before), dataclasses.asdict(after)
+    return {k: {w: n - a[k].get(w, 0) for w, n in v.items()}
+            if isinstance(v, dict) else v - a[k] for k, v in b.items()}
+
+
 def serve(engine, traffic: Traffic, seconds: float, trace_dir: Optional[str],
           clock: CompileClock, record: bool):
     """Serve the traffic from its warm-up through the window's close.
 
     Returns (window open in perf_counter seconds, steps, compiles in the
-    window, traced stretch (start, stop) from window open or None)."""
+    window, traced stretch (start, stop) from window open or None, the
+    growth of the engine's counters over the window's steps)."""
     import jax
     from repro.serving import SamplingParams
     from repro.serving.config import EngineError
@@ -212,13 +228,14 @@ def serve(engine, traffic: Traffic, seconds: float, trace_dir: Optional[str],
     steps: List[Step] = []
     t_open = time.perf_counter() + traffic.warmup_s
     trace_at = max(0.0, seconds - TRACE_S)
-    compiles0 = None
+    compiles0 = stats0 = None
     tracing = None
     traced = None
     while True:
         now = time.perf_counter() - t_open
         if compiles0 is None and now >= 0:
             compiles0 = clock.n
+            stats0 = copy.deepcopy(engine.stats)
         if trace_dir and traced is None and now >= trace_at:
             jax.profiler.start_trace(trace_dir)
             tracing = jax.profiler.TraceAnnotation(TRD.WINDOW_SPAN)
@@ -235,6 +252,8 @@ def serve(engine, traffic: Traffic, seconds: float, trace_dir: Optional[str],
                 except EngineError:
                     r.failed = True
                 r.submit_t = time.perf_counter() - t_open
+                if r.failed:
+                    traffic.finish(r, r.submit_t)
         if not live:
             nxt = traffic.next_due()
             with ann("bench.wait_arrival"):
@@ -258,13 +277,16 @@ def serve(engine, traffic: Traffic, seconds: float, trace_dir: Optional[str],
                 if o.finished:
                     r.output = list(o.output_token_ids)
                     r.done_t = t1
+                    traffic.finish(r, t1)
                     del live[o.rid]
     n_window = clock.n - (compiles0 if compiles0 is not None else clock.n)
+    stats = stats_delta(stats0 if stats0 is not None else engine.stats,
+                        engine.stats)
     if tracing is not None:
         traced[1] = time.perf_counter() - t_open
         tracing.__exit__(None, None, None)
         jax.profiler.stop_trace()
-    return t_open, steps, n_window, traced
+    return t_open, steps, n_window, traced, stats
 
 
 # -- metrics ------------------------------------------------------------------
@@ -358,6 +380,22 @@ def check(model: W.Model, seed: int, sample: List[Req], max_seq: int,
 # -- one run ------------------------------------------------------------------
 
 
+def traced_context(cell: SP.Cell, model: W.Model, n_slots: int,
+                   seconds: float, window: List[Req], steps: List[Step],
+                   traced, n_compiles: int, summary, staged, stats: dict,
+                   device_kind: str) -> Context:
+    """What the per-layer readers read after a traced run."""
+    policy = cell.config["policy"]
+    return Context(
+        model=model, policy=policy, kv="kv" + policy.split("kv", 1)[1],
+        n_slots=n_slots, seconds=seconds, window_reqs=window,
+        steps=[s for s in steps if 0 <= s.t0 and s.t1 <= seconds],
+        trace_steps=[s for s in steps if traced and traced[0] <= s.t0
+                     and s.t1 <= traced[1]],
+        compiles_in_window=n_compiles, trace=summary,
+        peaks=PK.peaks_for(device_kind), stages=staged, stats=stats)
+
+
 def run_cell(cell: SP.Cell, seed: int, seconds: float, trace: bool,
              t_start: float):
     """One run.  Returns (result line as a dict, check lines)."""
@@ -373,7 +411,7 @@ def run_cell(cell: SP.Cell, seed: int, seconds: float, trace: bool,
     phases.append(("warm-up", time.perf_counter()))
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
-        t_open, steps, n_compiles, traced = serve(
+        t_open, steps, n_compiles, traced, stats = serve(
             engine, traffic, seconds, trace_dir, clock, record=trace)
         setup_s = t_open - t_start
         phases.append(("traffic warm-up", t_open))
@@ -382,17 +420,18 @@ def run_cell(cell: SP.Cell, seed: int, seconds: float, trace: bool,
             in zip([("start", t_start)] + phases[:-1], phases))
             + f"; {clock.n} compiles ({clock.secs:.1f} s) before the window",
             file=sys.stderr)
-        summary = None
+        summary = staged = None
         if trace_dir:
             xp = TRD.find_xplane(trace_dir)
             summary = TRD.reduce(xp) if xp else None
+            staged = ST.reduce(xp) if xp else None
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    stats = dev.memory_stats() or {}
+    mem = dev.memory_stats() or {}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devs),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
+              "memory_peak_bytes": mem.get("peak_bytes_in_use")}
     max_seq, n_slots = engine.max_seq, engine.n_slots
     del engine
     gc.collect()
@@ -406,17 +445,9 @@ def run_cell(cell: SP.Cell, seed: int, seconds: float, trace: bool,
     result = {"correct": False, "attempted": len(window),
               "failed": sum(r.failed for r in window)}
     if trace:
-        peaks = PK.peaks_for(dev.device_kind)
-        in_trace = [s for s in steps if traced and traced[0] <= s.t0
-                    and s.t1 <= traced[1]]
-        ctx = Context(model=model,
-                      kv="kv" + cell.config["policy"].split("kv", 1)[1],
-                      n_slots=n_slots, seconds=seconds,
-                      window_reqs=window,
-                      steps=[s for s in steps if 0 <= s.t0
-                             and s.t1 <= seconds],
-                      trace_steps=in_trace, compiles_in_window=n_compiles,
-                      trace=summary, peaks=peaks)
+        ctx = traced_context(cell, model, n_slots, seconds, window, steps,
+                             traced, n_compiles, summary, staged, stats,
+                             dev.device_kind)
         result["metrics"] = SP.read_layer_metrics(cell.per_layer, ctx)
         if summary is not None:
             device["busy_s"] = summary.busy_s

@@ -6,14 +6,13 @@ program's own spans, named stages and counters read there.
 
 from the checkout root, on the chip.  The window, its traffic and its
 traced stretch (the last 10 s) are ``bench/run.py --trace 1``'s; the
-engine is wrapped only to keep each output's ``queue_time`` and the
-engine's counters after each step.  The last line of standard output is
-one JSON object:
+engine is wrapped only to keep each output's ``queue_time``.  The last
+line of standard output is one JSON object:
 
 * ``metrics``: ``step_host_ms`` (mean ``engine.step`` minus its
   ``engine.wait``), ``queue_wait_p95_ms`` (p95 of ``queue_time`` over the
-  window's requests), ``attn_grid_idle_share`` (1 - live / dispatched
-  cells of the paged kernel's grid in the window, in %), ``dev_ms.kv_io``,
+  window's requests), ``attn_grid_idle_share`` and ``gemm_roofline`` (as
+  their readers in ``bench/layer_metrics/`` read them), ``dev_ms.kv_io``,
   ``dev_ms.attn``, ``dev_ms.gemm``, ``dev_ms.sample`` (device ms per step
   program under those stages) and ``dev_unscoped_share`` (% of leaf-op
   time under no stage);
@@ -34,8 +33,6 @@ import time
 
 T_START = time.perf_counter()
 
-import copy  # noqa: E402
-import dataclasses  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -58,39 +55,20 @@ from bench.stats import mean_step_ms, p95  # noqa: E402
 
 class Observed:
     """The engine as ``harness.serve`` drives it, keeping each request's
-    queue time and the engine's counters after each step."""
+    queue time."""
 
     def __init__(self, engine):
         self.engine = engine
         self.queue_s = {}
-        self.marks = []          # (perf_counter, EngineStats) per step
 
     def __getattr__(self, name):
         return getattr(self.engine, name)
 
     def step(self):
         outs = self.engine.step()
-        self.marks.append((time.perf_counter(),
-                           copy.deepcopy(self.engine.stats)))
         for o in outs:
             self.queue_s.setdefault(o.rid, o.queue_time)
         return outs
-
-
-def stats_between(marks, lo: float, hi: float) -> dict:
-    """The counters' growth over the steps that ended in (lo, hi]."""
-    before = [s for t, s in marks if t <= lo]
-    after = [s for t, s in marks if t <= hi]
-    a = dataclasses.asdict(before[-1]) if before else None
-    b = dataclasses.asdict(after[-1])
-    out = {}
-    for k, v in b.items():
-        if isinstance(v, dict):
-            old = a[k] if a else {}
-            out[k] = {w: n - old.get(w, 0) for w, n in v.items()}
-        else:
-            out[k] = v - (a[k] if a else 0)
-    return out
 
 
 def main(argv=None) -> int:
@@ -103,7 +81,7 @@ def main(argv=None) -> int:
     cell = SP.load_cell(args.workload)
     H.place_compile_cache()
     try:
-        H.accelerator(cell.chips)
+        kind = H.accelerator(cell.chips)[0].device_kind
     except H.NoAccelerator as e:
         print(f"observe: {e}", file=sys.stderr)
         return 1
@@ -115,7 +93,7 @@ def main(argv=None) -> int:
     obs = Observed(engine)
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
     try:
-        t_open, steps, n_compiles, traced = H.serve(
+        t_open, steps, n_compiles, traced, stats = H.serve(
             obs, traffic, args.seconds, trace_dir, clock, record=True)
         xp = TRD.find_xplane(trace_dir)
         t0 = time.perf_counter()
@@ -131,16 +109,17 @@ def main(argv=None) -> int:
     window = [r for r in traffic.reqs if 0 <= r.due < args.seconds]
     queued = [obs.queue_s[r.rid] for r in window
               if r.rid in obs.queue_s and obs.queue_s[r.rid] is not None]
-    stats = stats_between(obs.marks, t_open, t_open + args.seconds)
+    ctx = H.traced_context(cell, model, engine.n_slots, args.seconds,
+                           window, steps, traced, n_compiles, None, summary,
+                           stats, kind)
     metrics = {
         "step_host_ms": summary.step_host_ms(),
         "queue_wait_p95_ms": p95(queued) * 1e3 if queued else None,
-        "attn_grid_idle_share": 100.0 * (
-            1 - stats["attn_live_cells"] / stats["attn_cells"])
-        if stats["attn_cells"] else None,
         "dev_unscoped_share": summary.unscoped_share()}
+    for name in ("attn_grid_idle_share", "gemm_roofline"):
+        metrics[name] = SP.reader(name)(ctx)
     for g in ST.METRIC_GROUPS:
-        metrics[f"dev_ms.{g}"] = summary.dev_ms(g)
+        metrics[f"dev_ms.{g}"] = ctx.stages.dev_ms(g)
 
     def by_width(sel):
         return {"decode": mean_step_ms([s for s in sel if s.width == 1]),

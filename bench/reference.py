@@ -3,7 +3,10 @@
 the program's.  It imports nothing of the program and draws its own
 weights from the seed (:mod:`bench.weights`).
 
-The block: pre-norm RMSNorm with gain ``1 + g``; q/k/v projections with
+The block: pre-norm RMSNorm with gain ``1 + g``; q/k/v projections
+``q = h Wq + bq``, ``k = h Wk + bk``, ``v = h Wv + bv``, the biases added
+in float32 after the projections and before the rotary embedding where
+the model has ``qkv_bias`` (and absent where it has not), with
 grouped-query attention (query head ``h`` reads KV head ``h // (H /
 H_kv)``); rotary embedding on the leading ``rotary_pct`` of each head in
 interleaved pairs; causal softmax attention scaled by ``head_dim**-0.5``;
@@ -59,9 +62,12 @@ def layer_forward(x, w, m: W.Model, kv_bits: Optional[int]):
     h_, hkv, hd = m.n_heads, m.n_kv_heads, m.head_dim
     pos = jnp.arange(s)
     h = rms_norm(x, w["ln1"], m.norm_eps)
-    q = (h @ w["wq"]).reshape(b, s, h_, hd)
-    k = (h @ w["wk"]).reshape(b, s, hkv, hd)
-    v = (h @ w["wv"]).reshape(b, s, hkv, hd)
+    q, k, v = h @ w["wq"], h @ w["wk"], h @ w["wv"]
+    if m.qkv_bias:
+        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
+    q = q.reshape(b, s, h_, hd)
+    k = k.reshape(b, s, hkv, hd)
+    v = v.reshape(b, s, hkv, hd)
     q = rope(q, pos, m.rotary_pct, m.rope_theta)
     k = rope(k, pos, m.rotary_pct, m.rope_theta)
     if kv_bits is not None:

@@ -53,6 +53,16 @@ def test_sound_run_is_correct_and_reports(sound):
     assert lines[0].startswith("check max_logit_gap")
 
 
+def test_closed_loop_run_is_correct(cpu):
+    """The tiny cell served end to end under a closed loop of four
+    clients: every client's next request is sent as its last finishes."""
+    result, _ = H.run_cell(tiny.cell(traffic=tiny.CLOSED_TRAFFIC), tiny.SEED,
+                           SECONDS, False, time.perf_counter())
+    assert result["correct"] is True
+    assert result["attempted"] > 4 and result["failed"] == 0
+    assert result["check"]["served_tokens"]["value"] > 0
+
+
 def test_control_is_not_correct(cpu):
     """The control's tokens, judged by the run's own ``check``."""
     check = H.check

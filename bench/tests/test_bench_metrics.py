@@ -1,16 +1,20 @@
 """The metric arithmetic: tails over all requests, censored TTFT, rates
 over the whole window, and the per-layer readers and work functions."""
 import dataclasses
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from bench import harness as H
 from bench import spec as SP
+from bench import stages as ST
 from bench import work
 from bench.loadgen import Req
 from bench.trace_reduce import TraceSummary
 from bench.weights import Model
+
+STAGED_TRACE = Path(__file__).with_name("data") / "two_steps_staged.xplane.pb"
 
 
 def req(due, tokens, submit=None):
@@ -76,14 +80,16 @@ def ctx(**kw):
     steps = [H.Step(0.0, 0.1, 1, [(5, 1), (9, 1)], 2),
              H.Step(0.1, 0.4, 16, [(0, 16), (7, 1)], 1),
              H.Step(0.4, 0.5, 1, [(6, 1)], 1)]
-    base = dict(model=MODEL, kv="kv8", n_slots=4, seconds=1.0,
+    base = dict(model=MODEL, policy="w4a16kv8", kv="kv8", n_slots=4,
+                seconds=1.0,
                 window_reqs=[req(0.1, [0.3], submit=0.15)], steps=steps,
                 trace_steps=steps, compiles_in_window=0,
                 trace=TraceSummary(window_s=0.5, busy_s=0.4, n_devices=1,
                                    op_s={"fusion.1": 0.1,
                                          "paged_kvattn.3": 0.2},
                                    op_detail={}, idle_gaps=[]),
-                peaks=H.PK.peaks_for("TPU v5 lite"))
+                peaks=H.PK.peaks_for("TPU v5 lite"), stages=None,
+                stats=None)
     base.update(kw)
     return H.Context(**base)
 
@@ -130,3 +136,66 @@ def test_readers_without_a_trace_read_nothing():
     for name in ("device_idle_share", "paged_attn_roofline", "step_mfu",
                  "gen_lag_p95_ms"):
         assert read(name, c) is None
+
+
+def test_gemm_work_counts_by_hand():
+    m = vars(MODEL)
+    rows = [(3, 1), (0, 2)]
+    assert work.gemm_flops(m, rows, emitted=1) + work.attn_flops(m, rows) \
+        == work.step_flops(m, rows, emitted=1)
+    # leaves (K, N): wq 8x8, wk 8x4, wv 8x4, wo 8x8, w1 8x16, w3 8x16,
+    # w2 16x8; groups of 32 do not divide K, so an error for the model
+    with pytest.raises(ValueError):
+        work.gemm_bytes(m, rows, 1, "w4a16kv8")
+    big = dict(m, d_model=64, n_heads=2, n_kv_heads=1, head_dim=32, d_ff=128)
+    leaves = [(64, 64), (64, 32), (64, 32), (64, 64), (64, 128), (64, 128),
+              (128, 64)]
+    weights = sum(k * n / 2 + k // 64 * n * 4 if k == 64
+                  else k * n / 2 + k // 128 * n * 4 for k, n in leaves)
+    act = 4 * 64 + 2 * 2 * 32 + 2 * 1 * 32 + 3 * 128
+    want = 2 * (weights + 2 * act * 3) + 2 * 64 * 32 + 2 * (64 + 32) * 1
+    assert work.gemm_bytes(big, rows, 1, "w4a16kv8") == want
+    biased = dict(big, qkv_bias=True)
+    assert work.gemm_bytes(biased, rows, 1, "w4a16kv8") == \
+        want + 2 * (2 + 2) * 32 * 2
+    # 16-bit weights: two bytes each and no scales
+    w16 = 2 * (sum(k * n * 2 for k, n in leaves) + 2 * act * 3) \
+        + 2 * 64 * 32 + 2 * (64 + 32)
+    assert work.gemm_bytes(big, rows, 1, "w16a16kv16") == w16
+    with pytest.raises(ValueError):
+        work.weight_bits("fp8a16kv8")
+
+
+def smollm():
+    return Model.from_config(SP.load_json(SP.BENCH / "configs"
+                                          / "smollm-360m.json"))
+
+
+def test_new_readers_on_the_staged_trace_and_hand_made_stats():
+    """Both readers read the recorded two-step trace of a TPU v5e (two
+    decode steps of the SmolLM cell) and hand-made engine counters."""
+    staged = ST.reduce(str(STAGED_TRACE))
+    steps = [H.Step(0.0, 0.03, 1, [(100 + i, 1) for i in range(32)], 32)
+             for _ in range(2)]
+    c = ctx(model=smollm(), stages=staged, trace_steps=steps,
+            stats={"attn_cells": 1000, "attn_live_cells": 70})
+    m = vars(c.model)
+    least = work.least_time(work.gemm_flops(m, steps[0].rows, 32),
+                            work.gemm_bytes(m, steps[0].rows, 32,
+                                            "w4a16kv8"), 197e12, 819e9)
+    assert least[1] == "memory"
+    got = read("gemm_roofline", c)
+    assert 0 < got <= 100
+    assert got == pytest.approx(100 * least[0] * 1e3
+                                / staged.dev_ms("gemm"))
+    assert read("attn_grid_idle_share", c) == pytest.approx(93.0)
+    assert read("attn_grid_idle_share", ctx(stats={
+        "attn_cells": 10, "attn_live_cells": 10})) == 0.0
+
+
+def test_new_readers_without_stages_or_counters_read_nothing():
+    c = ctx(model=smollm())
+    assert read("gemm_roofline", c) is None
+    assert read("attn_grid_idle_share", c) is None
+    assert read("attn_grid_idle_share", ctx(stats={
+        "attn_cells": 0, "attn_live_cells": 0})) is None

@@ -18,6 +18,11 @@ TRAFFIC = {
                "max_seq": 32, "block_size": 16, "prefill_chunk": 16,
                "enable_prefix_caching": False, "seed": 0}}
 
+#: the same, offered by a closed loop of four clients that send again at
+#: once
+CLOSED_TRAFFIC = dict(TRAFFIC, arrivals={"kind": "closed", "clients": 4,
+                                         "think_s": 0.0})
+
 #: set from CPU readings of this tiny cell at a 1.5 s window: the program
 #: reads 0.0 to 0.0126 over twelve seeds, the kv4 control 0.054 to 0.170;
 #: at this seed 0.0 and 0.147
@@ -25,9 +30,9 @@ LIMIT = 0.03
 SEED = 2 ** 31 + 78
 
 
-def cell(name="smollm360m.alpaca_poisson") -> SP.Cell:
+def cell(name="smollm360m.alpaca_poisson", traffic=TRAFFIC) -> SP.Cell:
     spec = SP.benchmark()
-    return SP.Cell(name=name, chips=1, config=CONFIG, traffic=TRAFFIC,
+    return SP.Cell(name=name, chips=1, config=CONFIG, traffic=traffic,
                    limits={"max_logit_gap": LIMIT},
                    end_to_end=SP.metrics_for(spec, "end_to_end", name),
                    per_layer=SP.metrics_for(spec, "per_layer", name))

@@ -2,8 +2,9 @@
 
 The model a cell serves is defined here, from ``--seed``: every GEMM
 weight is int4 codes times a float32 scale per (group of ``group`` rows,
-column), the form a w4 checkpoint has; embeddings, the LM head and the
-norm gains are bfloat16.  Two consumers draw the same numbers from the
+column), the form a w4 checkpoint has; embeddings, the LM head, the
+norm gains and, where the model has them, the q/k/v biases are
+bfloat16.  Two consumers draw the same numbers from the
 seed, each by itself:
 
 * :func:`program_params` packs the codes into the serving program's own
@@ -29,6 +30,14 @@ CODE_STD = 2.0
 QMAX = 7
 EMBED_STD = 0.02
 NORM_STD = 0.1
+#: std of the q/k/v biases.  The projections' outputs have a std of
+#: about 1 (codes times scales of std ~ 1/sqrt(K) over unit-RMS inputs),
+#: so a bias of half that moves every attention score and value by a
+#: visible share: left out, they move the tiny test cell's widest logit
+#: gap by 0.85-1.0, about thirty times its limit of 0.03
+#: (bench/tests/test_bench_model.py)
+BIAS_STD = 0.5
+BIAS_LEAVES = ("bq", "bk", "bv")
 GEMM_LEAVES = ("wq", "wk", "wv", "wo", "w1", "w3", "w2")
 
 
@@ -48,11 +57,18 @@ class Model:
     rope_theta: float
     rotary_pct: float
     norm_eps: float
+    qkv_bias: bool = False
 
     @classmethod
     def from_config(cls, config: dict) -> "Model":
+        """The ``model`` entry; a key this class does not know is an
+        error, never dropped."""
         m = config["model"]
-        return cls(**{f.name: m[f.name] for f in dataclasses.fields(cls)})
+        unknown = sorted(set(m) - {f.name for f in dataclasses.fields(cls)})
+        if unknown:
+            raise ValueError(f"{config.get('name')}: unknown model key(s) "
+                             f"{', '.join(unknown)}")
+        return cls(**m)
 
 
 def leaf_shapes(m: Model) -> Dict[str, Tuple[int, int]]:
@@ -96,8 +112,14 @@ def draw_leaf(key, k: int, n: int):
     return codes, scales
 
 
+def bias_shapes(m: Model) -> Dict[str, int]:
+    return {"bq": m.n_heads * m.head_dim, "bk": m.n_kv_heads * m.head_dim,
+            "bv": m.n_kv_heads * m.head_dim}
+
+
 def draw_layer(key, m: Model, layer) -> dict:
-    """Codes and scales of every GEMM leaf of one layer, and its norms."""
+    """Codes and scales of every GEMM leaf of one layer, its norms, and
+    its q/k/v biases where the model has them."""
     lk = _layer_key(key, layer)
     out = {name: draw_leaf(jax.random.fold_in(lk, i), *shape)
            for i, (name, shape) in enumerate(leaf_shapes(m).items())}
@@ -105,6 +127,10 @@ def draw_layer(key, m: Model, layer) -> dict:
                               NORM_STD)
     out["ln2"] = _bf16_normal(jax.random.fold_in(lk, 101), (m.d_model,),
                               NORM_STD)
+    if m.qkv_bias:
+        for i, (name, n) in enumerate(bias_shapes(m).items()):
+            out[name] = _bf16_normal(jax.random.fold_in(lk, 200 + i), (n,),
+                                     BIAS_STD)
     return out
 
 
@@ -137,8 +163,8 @@ def layer_f32(key, m: Model, layer) -> dict:
     """One layer's weights in float32, as the reference uses them."""
     d = draw_layer(key, m, layer)
     out = {name: dequant(*d[name]) for name in GEMM_LEAVES}
-    out["ln1"] = d["ln1"].astype(jnp.float32)
-    out["ln2"] = d["ln2"].astype(jnp.float32)
+    for name in ("ln1", "ln2") + (BIAS_LEAVES if m.qkv_bias else ()):
+        out[name] = d[name].astype(jnp.float32)
     return out
 
 
@@ -146,7 +172,8 @@ def program_params(m: Model, seed: int, policy: str):
     """The serving program's parameter tree for this model and seed: GEMM
     leaves packed in the format the program's own quantizer would choose
     for the policy (its tiles, and its scale groups where they are finer
-    than the model's), the rest bfloat16."""
+    than the model's), the rest bfloat16; the q/k/v biases, where the
+    model has them, as leaves ``bq``, ``bk``, ``bv`` stacked over layers."""
     return _program_maker(m, policy)(root_key(seed))
 
 
@@ -176,7 +203,8 @@ def _program_maker(m: Model, policy: str):
     def make(key):
         def layer(_, i):
             d = draw_layer(key, m, i)
-            out = {"ln1": d["ln1"], "ln2": d["ln2"]}
+            out = {name: d[name] for name in ("ln1", "ln2")
+                   + (BIAS_LEAVES if m.qkv_bias else ())}
             for name in GEMM_LEAVES:
                 codes, scales = d[name]
                 if fmt[name] is None:

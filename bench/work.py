@@ -9,7 +9,10 @@ computed from them can only rise when the implementation wastes less.
 """
 from __future__ import annotations
 
+import re
 from typing import Iterable, Tuple
+
+from bench import weights as W
 
 Rows = Iterable[Tuple[int, int]]
 
@@ -38,15 +41,54 @@ def attn_flops(m: dict, rows: Rows) -> float:
     return float(per_key * total)
 
 
+def gemm_flops(m: dict, rows: Rows, emitted: int) -> float:
+    """FLOPs of one step's GEMMs: every layer's projections and MLP for
+    every valid row, and the LM head for each emitted token."""
+    valid = sum(v for _, v in rows)
+    dense = 2.0 * m["n_layers"] * gemm_params_per_layer(m) * valid
+    return dense + 2.0 * m["d_model"] * m["vocab"] * emitted
+
+
 def step_flops(m: dict, rows: Rows, emitted: int) -> float:
     """Useful FLOPs of one engine step: the GEMMs of every valid row,
     attention over each valid row's context, and the LM head for each
     emitted token."""
     rows = list(rows)
+    return gemm_flops(m, rows, emitted) + attn_flops(m, rows)
+
+
+def weight_bits(policy: str) -> int:
+    """Stored bits per weight of a policy such as ``w4a16kv8``."""
+    got = re.match(r"w(\d+)a", policy)
+    if got is None:
+        raise ValueError(f"no integer weight width in policy {policy!r}")
+    return int(got.group(1))
+
+
+def gemm_bytes(m: dict, rows: Rows, emitted: int, policy: str) -> float:
+    """Least HBM bytes of one step's GEMMs: every GEMM leaf of every layer
+    once at the policy's stored weight width, with its float32 scales at
+    the model's own group (:func:`bench.weights.group_of`) where that
+    width is below 16 bits; the qkv biases in bf16 where the model has
+    them; the bf16 LM head once; and the bf16 activations of the valid
+    rows, each GEMM's input (counted once where GEMMs share it) and
+    output, and the head's for each emitted token."""
+    bits = weight_bits(policy)
+    d, h, hkv, hd, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                        m["head_dim"], m["d_ff"])
+    layer = 0.0
+    for k, n in W.leaf_shapes(W.Model(**m)).values():
+        layer += k * n * bits / 8
+        if bits < 16:
+            layer += k // W.group_of(k) * n * 4
+    if m.get("qkv_bias"):
+        layer += (h + 2 * hkv) * hd * 2
+    # q/k/v: x in, q, k, v out; wo: attention in, d out; w1/w3: x in,
+    # two f out; w2: f in, d out
+    act = 4 * d + 2 * h * hd + 2 * hkv * hd + 3 * f
     valid = sum(v for _, v in rows)
-    dense = 2.0 * m["n_layers"] * gemm_params_per_layer(m) * valid
-    head = 2.0 * m["d_model"] * m["vocab"] * emitted
-    return dense + attn_flops(m, rows) + head
+    return (m["n_layers"] * (layer + 2.0 * act * valid)
+            + 2.0 * d * m["vocab"] + 2.0 * (d + m["vocab"]) * emitted)
 
 
 def paged_attn_bytes(m: dict, rows: Rows, kv: str) -> float:

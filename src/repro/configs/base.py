@@ -28,6 +28,7 @@ class ModelConfig:
     window: Optional[int] = None        # sliding-window size (local layers)
     local_global_period: int = 0        # gemma3: 6 → every 6th layer global
     use_rope: bool = True               # whisper: sinusoidal instead
+    qkv_bias: bool = False              # chatglm3: biased q/k/v projections
 
     # MoE ----------------------------------------------------------------
     n_experts: int = 0
@@ -67,6 +68,12 @@ class ModelConfig:
     def attn_free(self) -> bool:
         return self.family == "ssm"
 
+    def _attn_params(self) -> int:
+        """q/k/v/o projections of one layer, with the q/k/v biases."""
+        d, hd, H, Hkv = self.d_model, self.hd, self.n_heads, self.n_kv_heads
+        bias = (H + 2 * Hkv) * hd if self.qkv_bias else 0
+        return d * H * hd + 2 * d * Hkv * hd + H * hd * d + bias
+
     def param_count(self) -> int:
         """Approximate parameter count (for roofline MODEL_FLOPS)."""
         d, f, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab
@@ -76,7 +83,7 @@ class ModelConfig:
             # uses k: d→f, v: f→d, r: d→d)
             per = 5 * d * d + 2 * d * f + d * d
         else:
-            attn = d * H * hd + 2 * d * Hkv * hd + H * hd * d
+            attn = self._attn_params()
             mlp = 3 * d * f
             if self.n_experts:
                 moe = self.n_experts * 3 * d * f + d * self.n_experts
@@ -102,9 +109,8 @@ class ModelConfig:
         MODEL_FLOPS = 6·N_active·D."""
         if not self.n_experts:
             return self.param_count()
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        hd, H, Hkv, V = self.hd, self.n_heads, self.n_kv_heads, self.vocab
-        attn = d * H * hd + 2 * d * Hkv * hd + H * hd * d
+        d, f, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab
+        attn = self._attn_params()
         act_mlp = self.topk * 3 * d * f + d * self.n_experts
         if self.moe_dense_residual or self.shared_expert:
             act_mlp += 3 * d * f

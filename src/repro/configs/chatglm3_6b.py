@@ -1,7 +1,10 @@
 """ChatGLM3-6B — dense decoder with 2D/partial RoPE (rotation on half the
-head dim) and aggressive GQA (kv=2).  [arXiv:2406.12793]
+head dim), aggressive GQA (kv=2) and biased q/k/v projections
+(``add_qkv_bias``).  [arXiv:2406.12793]
 
-Assigned spec: 28L d_model=4096 32H (GQA kv=2) d_ff=13696 vocab=65024.
+Published config (THUDM/chatglm3-6b ``config.json``): 28L d_model=4096
+32H of 128 (multi-query groups 2) ffn 13696 padded vocab 65024, RMSNorm,
+q/k/v bias and no other linear bias, untied head.
 """
 from .base import ModelConfig
 
@@ -15,7 +18,8 @@ CONFIG = ModelConfig(
     d_ff=13696,
     vocab=65024,
     rotary_pct=0.5,          # "RoPE 2d": rotate the leading half of head_dim
-    source="arXiv:2406.12793",
+    qkv_bias=True,
+    source="https://huggingface.co/THUDM/chatglm3-6b/blob/main/config.json",
 )
 
 REDUCED = ModelConfig(
@@ -28,5 +32,6 @@ REDUCED = ModelConfig(
     d_ff=512,
     vocab=1024,
     rotary_pct=0.5,
-    source="reduced variant of arXiv:2406.12793",
+    qkv_bias=True,
+    source="reduced variant of the published chatglm3-6b config",
 )

@@ -25,9 +25,16 @@ from .packing import PackedWeight, dequantize_packed, unpack_weight
 from .precision import PrecisionPolicy
 
 
+#: the name-stack scope of the XLA paths' weight unpack: ops XLA fuses
+#: into the dot keep the dot's name, the rest carry this one under the
+#: caller's stage
+DEQUANT_SCOPE = "dequant"
+
+
 def _dequant_fused(p: PackedWeight, dtype=jnp.bfloat16) -> jax.Array:
     """Dequantize for the fused XLA path (convert feeds straight into dot)."""
-    return dequantize_packed(p, dtype=dtype)
+    with jax.named_scope(DEQUANT_SCOPE):
+        return dequantize_packed(p, dtype=dtype)
 
 
 def mp_matmul(
@@ -57,7 +64,8 @@ def mp_matmul(
         # W8A8 / W4A8: native MXU s8×s8→s32 with per-token × per-group
         # rescale (unpack_weight yields s8-held values for both widths).
         xq, xscale = Q.quantize_act_per_token(x, bits=8)
-        qw = unpack_weight(w)                     # (K, N) int8
+        with jax.named_scope(DEQUANT_SCOPE):
+            qw = unpack_weight(w)                 # (K, N) int8
         acc = jax.lax.dot_general(
             xq, qw, (((xq.ndim - 1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)

@@ -13,6 +13,7 @@ FSDP-style weight sharding on the data axes (big_model archs):
   treated as a stack dim; expert parallelism is the §Perf all-to-all
   variant (launch/expert_parallel.py)
 * embeddings: vocab on "model" when divisible, else replicated
+* q/k/v biases: features on "model" as their projection's columns
 * KV caches: batch on data axes; heads on "model" when divisible
   (they rarely are at 16-way TP with GQA), else **sequence-parallel** —
   the flash-decoding-across-chips layout from DESIGN.md §6
@@ -41,6 +42,8 @@ _COLUMN = ("wq", "wk", "wv", "w1", "w3", "ws1", "ws3", "we1", "we3",
            "cr", "lm_head")
 _ROW = ("wo", "w2", "ws2", "we2", "cv", "xwo")
 _REPLICATED = ("router", "w_A", "w_B", "img_proj")   # small / odd shapes
+#: q/k/v bias → the projection whose output columns it is added to
+_BIAS_OF = {"bq": "wq", "bk": "wk", "bv": "wv"}
 
 
 def _name_of(path) -> str:
@@ -174,6 +177,14 @@ class ShardingRules:
         if wname == "embed":
             V = leaf.shape[0]
             return P(self.model if _div(V, self.model_size) else None)
+        if wname in _BIAS_OF:
+            # (..., N): features on "model" where its projection's
+            # columns go there (whole heads per device), else replicated
+            spec = [None] * leaf.ndim
+            if self._heads_ok(_BIAS_OF[wname]) and \
+                    _div(leaf.shape[-1], self.model_size):
+                spec[-1] = self.model
+            return P(*spec)
         if wname == "dec_pos" or leaf.ndim <= 1:
             return P()
         style = _style(wname)

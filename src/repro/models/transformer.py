@@ -33,6 +33,12 @@ from repro.kernels.kvattn import NO_WINDOW as BIG_WINDOW  # noqa: E402
 # Init
 # ---------------------------------------------------------------------------
 
+#: the q/k/v bias leaves of a ``qkv_bias`` model, stacked ``(L, width)``
+QKV_BIAS = ("bq", "bk", "bv")
+#: std of the initial biases: half the projections' output std, so that
+#: the adds move every score and value visibly
+BIAS_STD = 0.5
+
 
 def init_layer_stack(cfg: ModelConfig, key) -> Dict[str, Any]:
     L, d, f = cfg.n_layers, cfg.d_model, cfg.d_ff
@@ -47,6 +53,12 @@ def init_layer_stack(cfg: ModelConfig, key) -> Dict[str, Any]:
         "wv": C.dense_init(ks["wv"], (L, d, Hkv * hd)),
         "wo": C.dense_init(ks["wo"], (L, H * hd, d)),
     }
+    if cfg.qkv_bias:
+        # drawn from a key of their own, so every other leaf is the same
+        # with or without them
+        kb = jax.random.split(jax.random.fold_in(key, 1), 3)
+        for kk, name, n in zip(kb, QKV_BIAS, (H * hd, Hkv * hd, Hkv * hd)):
+            p[name] = C.dense_init(kk, (L, n), scale=BIAS_STD)
     if cfg.n_experts:
         E = cfg.n_experts
         p["router"] = C.dense_init(ks["router"], (L, d, E), scale=0.02)
@@ -100,12 +112,18 @@ def layer_window(cfg: ModelConfig, layer_idx) -> jax.Array:
 
 
 def qkv(h, lp, cfg: ModelConfig, policy, impl):
+    """The q/k/v projections, each plus its bias where the model has
+    them (in the projection's output dtype, before the heads are split
+    and the rotary embedding is applied)."""
     B, T, _ = h.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = C.linear(h, lp["wq"], policy, impl).reshape(B, T, H, hd)
-    k = C.linear(h, lp["wk"], policy, impl).reshape(B, T, Hkv, hd)
-    v = C.linear(h, lp["wv"], policy, impl).reshape(B, T, Hkv, hd)
-    return q, k, v
+    outs = []
+    for w, b, heads in zip(("wq", "wk", "wv"), QKV_BIAS, (H, Hkv, Hkv)):
+        y = C.linear(h, lp[w], policy, impl)
+        if cfg.qkv_bias:
+            y = y + lp[b].astype(y.dtype)
+        outs.append(y.reshape(B, T, heads, hd))
+    return tuple(outs)
 
 
 def ffn(h, lp, cfg: ModelConfig, policy, impl):

@@ -7,9 +7,11 @@ Nothing runs: each case compiles the kernel for one chip of a *described*
 shapes off the (8, 128) tiling, vector layouts and ops Mosaic refuses,
 too much VMEM.  The engine's whole paged step is compiled too, to check
 that it leaves the KV pool where it is: no relayout or copy of it, and
-the pool aliased from the step's input to its output.  The topology is described inside a module fixture, so
-only the worker that runs this file loads the TPU compiler, and the file
-skips where no topology can be described.
+the pool aliased from the step's input to its output, at SmolLM-360M's
+widths and at ChatGLM3-6B's (d_model 4096, 32 heads over 2 KV heads of
+128, d_ff 13696, q/k/v biases).  The topology is described inside a
+module fixture, so only the worker that runs this file loads the TPU
+compiler, and the file skips where no topology can be described.
 """
 import dataclasses
 import os
@@ -189,11 +191,11 @@ _ARRAY = r"(\w+)\[([\d,]*)\]\{([^}]*)\}"
 
 
 def _relayouts(hlo: str):
-    """(dims, bytes) of the result of every transpose, and of every copy
-    or async copy whose layout differs from its operand's: the copies
-    that move data into another layout.  A copy that only moves an array
-    between memory spaces (``S(n)``, the compiler's prefetches into
-    on-chip memory) keeps its layout and is left out."""
+    """(element type, dims, bytes) of the result of every transpose, and
+    of every copy or async copy whose layout differs from its operand's:
+    the copies that move data into another layout.  A copy that only
+    moves an array between memory spaces (``S(n)``, the compiler's
+    prefetches into on-chip memory) keeps its layout and is left out."""
     layouts, moves = {}, []
     for line in hlo.splitlines():
         m = re.match(r"\s*(?:ROOT )?(%\S+) = (.*?) ([\w-]+)\((.*)", line)
@@ -214,7 +216,7 @@ def _relayouts(hlo: str):
                 (src[1], plain(src[2])) == (dims, plain(layout)):
             continue
         dims = tuple(int(x) for x in dims.split(",") if x)
-        out.append((dims, int(np.prod(dims)) * _BYTES.get(dt, 4)))
+        out.append((dt, dims, int(np.prod(dims)) * _BYTES.get(dt, 4)))
     return out
 
 
@@ -229,9 +231,44 @@ def test_paged_step_moves_no_pool(one_chip, smollm_engines, monkeypatch,
     is aliased from the step's input to its output (the step donates it
     and the kernels read and write it in place).  Weight relayouts are
     not the pool's: they are told apart by the block axis."""
+    _step_moves_no_pool(one_chip, smollm_engines(fmt), monkeypatch, T, CTX)
+
+
+GLM_CTX = 2048                          # the ChatGLM3-6B cell's max_seq
+
+
+@pytest.fixture(scope="module")
+def glm_engine():
+    """The ChatGLM3-6B cell's paged engine (32 slots x 2048 tokens, block
+    16, chunk 32, kv8) with two layers at its published widths, q/k/v
+    biases included.  Its weights are shapes only: nothing is run."""
+    from repro.configs import get_config
+    from repro.core.precision import get_policy
+    from repro.models.transformer import init_params
+    from repro.serving import Engine, EngineConfig
+    from repro.serving.engine import quantize_params
+    cfg = dataclasses.replace(get_config("chatglm3-6b"), n_layers=L)
+    assert cfg.qkv_bias and (cfg.hd, cfg.n_kv_heads) == (GLM_D, GLM_HKV)
+    policy = "w4a16kv8"
+    params = jax.eval_shape(lambda k: quantize_params(
+        init_params(cfg, k), get_policy(policy)), jax.random.PRNGKey(0))
+    return Engine(EngineConfig(
+        model=cfg, policy=policy, cache_kind="paged", attn_impl="kernel",
+        n_slots=32, max_seq=GLM_CTX, block_size=BS, prefill_chunk=CHUNK),
+        params=params)
+
+
+@pytest.mark.parametrize("T", [1, CHUNK])
+def test_paged_step_moves_no_pool_at_chatglm3_widths(one_chip, glm_engine,
+                                                     monkeypatch, T):
+    """The same at ChatGLM3-6B's widths: 16 query heads per KV head of
+    128, the biased q/k/v projections, the top live bucket."""
+    _step_moves_no_pool(one_chip, glm_engine, monkeypatch, T, GLM_CTX)
+
+
+def _step_moves_no_pool(one_chip, eng, monkeypatch, T, ctx):
     from repro.kernels import ops as kops
     monkeypatch.setattr(kops, "INTERPRET", False)
-    eng = smollm_engines(fmt)
     cache, n_slots = eng.cache, eng.n_slots
     sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
         np.shape(a), a.dtype, sharding=one_chip)
@@ -242,12 +279,20 @@ def test_paged_step_moves_no_pool(one_chip, smollm_engines, monkeypatch,
         jax.ShapeDtypeStruct((n_slots, T), jnp.int32, sharding=one_chip),
         jax.tree.map(sds, cache), z(jnp.int32), z(jnp.int32),
         z(jnp.uint32), z(jnp.int32), z(jnp.float32), z(jnp.int32),
-        max_live=CTX).compile().as_text()
+        max_live=ctx).compile().as_text()
     assert "tpu_custom_call" in hlo
 
     layer_k = cache.k[0].size * cache.k.dtype.itemsize
-    moved = [(dims, n) for dims, n in _relayouts(hlo)
-             if cache.n_blocks in dims and n >= layer_k]
+    pool_dt = {_HLO_DTYPE[a.dtype.name]
+               for a in (cache.k, cache.v, cache.k_scale, cache.v_scale)}
+    # Where the block count equals d_model (ChatGLM3-6B's widths), the
+    # weights' relayouts span it as well; there a relayout is the pool's
+    # if it keeps the block size as an axis or a pool element type.
+    weights_span = cache.n_blocks == eng.model_cfg.d_model
+    moved = [(dt, dims, n) for dt, dims, n in _relayouts(hlo)
+             if cache.n_blocks in dims and n >= layer_k
+             and (not weights_span or cache.block_size in dims
+                  or dt in pool_dt)]
     assert not moved, moved
 
     header = hlo.split("\n", 1)[0]
